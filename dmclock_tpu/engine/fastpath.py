@@ -122,6 +122,16 @@ def _ready_now(state: EngineState, now):
     return state.head_ready | (state.head_limit <= now)
 
 
+def on_tpu() -> bool:
+    """Whether the program being traced lands on a TPU: the platform
+    of the ``jax.default_device`` scope when one is set, else the
+    default backend's.  Picks the Pallas kernels at trace time."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend() == "tpu"
+    return (dev if isinstance(dev, str) else dev.platform) == "tpu"
+
+
 class RingWindow(NamedTuple):
     """Per-epoch prefetch of the tail rings.
 
@@ -138,14 +148,13 @@ class RingWindow(NamedTuple):
 
 
 # Pallas row-rotate: the barrel shift runs in VMEM (one HBM read +
-# write per chunk) instead of log2(Q) full HBM passes -- measured 3x
-# the XLA rolls at bench shapes.  Constraints of this TPU stack:
-# gridded pallas_call does not legalize through the remote Mosaic
-# compiler, so the kernel is gridless and the host slices VMEM-sized
-# row chunks; int64 rings are bitcast to int32 lane pairs (a row
+# write per chunk) instead of log2(Q) full HBM passes.  The kernel is
+# gridless and the host slices VMEM-sized row chunks (gridding it is
+# open perf work); int64 rings are bitcast to int32 lane pairs (a row
 # rotation by 2*q0 on the pair plane is the int64 rotation by q0).
 # The chunk scales inversely with ring width to stay inside the 16MB
-# scoped-VMEM budget (2048 rows was tuned at Q=128 = 256 lanes).
+# scoped-VMEM budget (2048 rows at Q=128 = 256 lanes; compiled for
+# v5e at N=100k in tests/test_tpu_compile.py).
 _ROT_LANE_BUDGET = 2048 * 256
 
 
@@ -226,16 +235,15 @@ def ring_window(state: EngineState, m: int,
     client drained, and are masked at commit.
 
     ``use_pallas`` overrides the backend auto-pick: callers that wrap
-    this in ``vmap`` must pass False -- batching adds a grid dimension
-    to the (deliberately gridless) kernel, and gridded pallas_calls do
-    not legalize through this environment's remote Mosaic compiler."""
+    this in ``vmap`` must pass False -- batching would add a grid
+    dimension to the gridless kernel."""
     q = state.ring_capacity
     q0 = state.q_head
     wsize = min(m, q)
 
     # the Pallas path needs a full lane tile (2q >= 128 int32 lanes)
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu" and q >= 64
+        use_pallas = on_tpu() and q >= 64
     if use_pallas:
         n = q0.shape[0]
         q0t = _tile_shifts(q0, q, n + ((-n) % _rot_chunk(q)))
@@ -1046,7 +1054,7 @@ def _batch_metrics(met, st: EngineState, *, count, resv, prop, lb,
                    guards_ok, rebase_fallback=False, live=True,
                    ladder_levels_used=0, ladder_base_decisions=0,
                    ladder_fallbacks=0, wheel_occ_hwm=0,
-                   wheel_reslots=0, pallas_fallbacks=0):
+                   wheel_reslots=0):
     """Fold one batch's contribution into the epoch metrics vector --
     pure reductions over arrays the batch already materialized, so the
     decision stream cannot be perturbed.  A stall is a batch that
@@ -1074,8 +1082,7 @@ def _batch_metrics(met, st: EngineState, *, count, resv, prop, lb,
         cal_ladder_base_decisions=ladder_base_decisions,
         cal_ladder_fallbacks=ladder_fallbacks,
         wheel_occ_hwm=wheel_occ_hwm,
-        wheel_reslots=wheel_reslots,
-        pallas_fallbacks=pallas_fallbacks))
+        wheel_reslots=wheel_reslots))
 
 
 def _telemetry_delta(st_post: EngineState, now, cls, key, served_pc,
@@ -1627,7 +1634,7 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
                    kresv, kprop1, kprop2, b_eff):
     """One dense pass of per-client serve iteration, as a lax.scan
     over the step axis (an unrolled step loop at steps=32 exploded
-    compile time through the remote compiler).
+    TPU compile time).
 
     With ``b_eff`` None: measure mode -- serve everything followable
     and return the per-client STOP pack (KEY_INF when the client ran
@@ -1988,24 +1995,29 @@ _WHEEL_STOP_SHIFT = 52   # stop packs live in [0, 2^60): 256 buckets
 
 def _wheel_resolve(wheel_kernel: str, n: int):
     """STATIC resolution of the ``wheel_kernel`` switch: returns
-    ``(scan_fn, fallback)`` with ``scan_fn(keys, slot, nb)`` matching
-    :func:`kernels.wheel_scan`.  "pallas" resolves to the real kernel
-    on TPU backends, to interpret mode anywhere when
-    ``DMCLOCK_WHEEL_INTERPRET=1`` (the CI parity path), and otherwise
-    falls back to the XLA reference with ``fallback=True`` -- counted
-    per live batch in the pallas_fallbacks metric row, so a fleet
-    silently running the fallback is visible in /metrics."""
+    ``scan_fn(keys, slot, nb)`` matching :func:`kernels.wheel_scan`.
+    "pallas" is the real kernel on TPU, or interpret mode anywhere
+    under ``DMCLOCK_WHEEL_INTERPRET=1`` (the CPU parity path); any
+    other "pallas" request -- off TPU, or a shape past the gridless
+    kernel's lane budget -- raises instead of running the reference
+    in its place."""
     if wheel_kernel not in _WHEEL_KERNELS:
         raise ValueError(f"unknown wheel_kernel {wheel_kernel!r} "
                          f"(one of {_WHEEL_KERNELS})")
-    if wheel_kernel == "pallas":
-        interpret = os.environ.get("DMCLOCK_WHEEL_INTERPRET") == "1"
-        if kernels_pallas.wheel_supported(n, 3 * _WHEEL_BUCKETS) and \
-                (interpret or jax.default_backend() == "tpu"):
-            return (functools.partial(kernels_pallas.wheel_scan_pallas,
-                                      interpret=interpret), False)
-        return kernels.wheel_scan, True
-    return kernels.wheel_scan, False
+    if wheel_kernel == "xla":
+        return kernels.wheel_scan
+    interpret = os.environ.get("DMCLOCK_WHEEL_INTERPRET") == "1"
+    if not (interpret or on_tpu()):
+        raise ValueError(
+            'wheel_kernel="pallas" needs a TPU (or '
+            "DMCLOCK_WHEEL_INTERPRET=1 for interpret mode); use "
+            'wheel_kernel="xla" here')
+    if not kernels_pallas.wheel_supported(n, 3 * _WHEEL_BUCKETS):
+        raise ValueError(
+            f'wheel_kernel="pallas" does not support n={n} clients '
+            f"(gridless lane budget {kernels_pallas.MAX_LANES})")
+    return functools.partial(kernels_pallas.wheel_scan_pallas,
+                             interpret=interpret)
 
 
 class WheelIndex(NamedTuple):
@@ -2315,7 +2327,7 @@ def calendar_batch_wheel(state: EngineState, now, *, steps: int,
     assert steps <= state.ring_capacity, \
         "calendar steps exceed the ring window"
     assert levels >= 1, "the ladder needs at least one level"
-    scan_fn, _fb = _wheel_resolve(wheel_kernel, state.capacity)
+    scan_fn = _wheel_resolve(wheel_kernel, state.capacity)
     invariant = {f: getattr(state, f) for f in _EPOCH_INVARIANT}
     mut0 = {f: getattr(state, f) for f in _EPOCH_MUTABLE}
     mut, acc, _tacc, (count, resv, bound, stall), _w = \
@@ -2431,10 +2443,9 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
     levels = int(ladder_levels) if bucketed else 1
     assert levels >= 1, "the ladder needs at least one level"
     if wheel:
-        wheel_fn, wheel_fb = _wheel_resolve(wheel_kernel,
-                                            state.capacity)
+        wheel_fn = _wheel_resolve(wheel_kernel, state.capacity)
     else:
-        wheel_fn, wheel_fb = None, False
+        wheel_fn = None
     narrow32 = tag_width == 32
     invariant = {f: getattr(state, f) for f in _EPOCH_INVARIANT}
     mutable0_64 = {f: getattr(state, f) for f in _EPOCH_MUTABLE}
@@ -2559,12 +2570,7 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
                 ladder_levels_used=levels_used,
                 ladder_base_decisions=base_decs,
                 ladder_fallbacks=ladder_fb,
-                wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots,
-                # static per-trace: the requested Pallas kernel
-                # resolved to the XLA reference for this program
-                pallas_fallbacks=jnp.where(
-                    good, jnp.int64(1 if wheel_fb else 0),
-                    jnp.int64(0)))
+                wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots)
         if need_tele:
             tele = _tele_fold(tele, hd, ld, good, sd)
             if "p" in tele:

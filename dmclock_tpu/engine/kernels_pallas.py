@@ -21,12 +21,12 @@ of the raw ones -- the (hi signed, lo unsigned) lexicographic order
 IS the int64 order, so per-bucket (min hi, min lo among hi-ties)
 reconstructs the exact int64 minimum.
 
-Environment constraints (same stack notes as fastpath's row-rotate
-kernel): the remote Mosaic compiler does not legalize gridded
-pallas_calls, so the kernel is gridless and loops the lane rows with
-``lax.fori_loop``; iotas are 2-D; all temporaries are [sublane, lane]
-shaped with the bucket axis on sublanes, which makes the per-row
-one-hot compare a plain broadcast with no transposes.
+Shape: the kernel is gridless (gridding it is open perf work) and
+loops the lane rows with ``lax.fori_loop``; iotas are 2-D; all
+temporaries are [sublane, lane] shaped with the bucket axis on
+sublanes, which makes the per-row one-hot compare a plain broadcast
+with no transposes.  tests/test_tpu_compile.py compiles it for v5e
+at n=100k, 3x256 buckets.
 """
 
 from __future__ import annotations
@@ -45,15 +45,14 @@ _I32_MAX = 0x7FFFFFFF
 # padded-lane budget for the gridless call: inputs are 3 int32 planes
 # (12 B/lane) and the one-hot temp is [nb, 128]; 2^19 lanes keeps the
 # whole working set well under the 16MB scoped-VMEM budget
-_MAX_LANES = 1 << 19
+MAX_LANES = 1 << 19
 
 
 def wheel_supported(n: int, nb: int) -> bool:
     """Static feasibility of the gridless kernel at [n] lanes and
-    ``nb`` buckets (the caller falls back to the XLA reference --
-    counted in the pallas_fallbacks metric row -- when False)."""
+    ``nb`` buckets (a "pallas" request past it raises)."""
     padded = -(-n // _LANES) * _LANES
-    return padded <= _MAX_LANES and nb % 8 == 0
+    return padded <= MAX_LANES and nb % 8 == 0
 
 
 def _wheel_kernel(bidx_ref, khi_ref, klo_ref, cnt_ref, mhi_ref,

@@ -43,6 +43,7 @@ from typing import List, Optional
 
 import numpy as np
 
+from ..utils.compile_cache import enable_compile_cache
 from .journal import ArrivalJournal
 from .server import IngestServer
 
@@ -384,6 +385,7 @@ def main(argv=None) -> int:
                     help="resume WITHOUT a live server: finish from "
                     "the journal alone (post-SIGKILL incarnation)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     with open(args.config, "r", encoding="utf-8") as f:
         cfg = _cfg_from_json(json.load(f))

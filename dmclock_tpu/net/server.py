@@ -87,6 +87,31 @@ class TakeResult(tuple):
     carry = property(lambda s: s[4])
 
 
+def notify_payloads(obj: dict) -> list:
+    """``obj`` packed as NOTIFY payloads of at most one frame each:
+    one payload when it fits, else ``verdicts`` cut into slices of
+    about half a frame (a slice that still overflows is halved),
+    numbered ``part`` of ``parts``."""
+    payload = framing.pack_notify(obj)
+    rows = obj.get("verdicts") or []
+    if len(payload) <= framing.MAX_FRAME or len(rows) < 2:
+        return [payload]
+    per = max(1, len(rows) * (framing.MAX_FRAME // 2) // len(payload))
+    todo = [rows[i:i + per] for i in range(0, len(rows), per)][::-1]
+    slices = []
+    while todo:
+        cur = todo.pop()
+        if len(cur) > 1 and len(framing.pack_notify(
+                dict(obj, verdicts=cur, part=0, parts=0))) \
+                > framing.MAX_FRAME:
+            todo += [cur[len(cur) // 2:], cur[:len(cur) // 2]]
+        else:
+            slices.append(cur)
+    return [framing.pack_notify(dict(obj, verdicts=sl, part=i,
+                                     parts=len(slices)))
+            for i, sl in enumerate(slices)]
+
+
 class IngestServer:
     """Threaded ingest front-end for one serving loop.
 
@@ -319,11 +344,14 @@ class IngestServer:
     # -- notifications -------------------------------------------------
     def publish(self, obj) -> None:
         """Queue one completion NOTIFY batch for every subscriber
-        (best-effort: subscribers are telemetry, never admission)."""
-        payload = framing.pack_notify(obj)
+        (best-effort: subscribers are telemetry, never admission).  A
+        batch past one frame -- per-client ``verdicts`` at 100k
+        clients run to tens of MB -- goes out as several NOTIFYs, each
+        with a slice of the verdicts and ``part``/``parts``."""
+        payloads = notify_payloads(obj)
         with self._lock:
             self.counters["notify_batches"] += 1
-        self._notify_q.append(payload)
+        self._notify_q.extend(payloads)
         self._wake()
 
     # -- status / metrics ----------------------------------------------

@@ -26,7 +26,7 @@ control path.
 
 And the time-domain tracing plane (``obs.spans``,
 ``obs.trace_export``, ``obs.watchdog``): a thread-safe ns-resolution
-host span tracer (nested spans, fixed category taxonomy, bounded
+host span tracer (nested spans, fixed category set, bounded
 ring), Chrome trace-event / Perfetto export so any run produces a
 ``chrome://tracing``-loadable timeline, and a steady-state watchdog
 that warns on launch-cadence stalls and dispatch-share breaches.

@@ -2,8 +2,8 @@
 
 Every hot launch path in the repo routes through a MODULE-LEVEL jit
 cache (the ``engine/queue.py`` ``_JIT_CACHE`` convention), because a
-re-trace costs seconds of host time and a re-compile on the remote
-Mosaic compiler has been measured north of 15 minutes (PROFILE.md) --
+re-trace costs seconds of host time and a TPU re-compile of the big
+fused programs costs tens of seconds to minutes (PROFILE.md) --
 a retrace STORM is a silicon-session-killing failure mode that today
 is invisible until the wall clock is already gone.  This module makes
 every one of those caches observable:
@@ -67,6 +67,15 @@ def clear_compiled() -> None:
     a retrace."""
     for w in list(_ALL_WRAPPERS):
         w.clear_compiled()
+
+
+def live_executables(cache: str) -> list:
+    """The AOT executables the wrappers of one cache family hold --
+    the programs that actually ran, for checks on their HLO (e.g. that
+    a Pallas kernel is in it: ``tpu_custom_call``)."""
+    return [c for w in list(_ALL_WRAPPERS) if w._cache == cache
+            for c in list(w._compiled.values()) if c is not _DISPATCH]
+
 
 # one retrace event ring entry per (re)trace, what the watchdog's
 # retrace-storm check windows over

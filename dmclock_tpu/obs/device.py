@@ -84,10 +84,10 @@ MET_WHEEL_RESLOTS = 18    # wheel calendar: in-place bucket re-slots
 #                           ladder levels / API adjust events -- the
 #                           O(moved) work the wheel does instead of a
 #                           full O(N) re-measure)
-MET_PALLAS_FALLBACKS = 19  # batches that requested wheel_kernel=
-#                            "pallas" but ran the XLA reference (non-
-#                            TPU backend or unsupported shape) -- a
-#                            fleet silently off its kernel is visible
+MET_PALLAS_FALLBACKS = 19  # retired, always 0: a wheel_kernel=
+#                            "pallas" request that cannot run raises
+#                            (PR 21) instead of running the XLA
+#                            reference; the row keeps the layout
 NUM_METRICS = 20
 
 METRIC_NAMES = (
@@ -137,15 +137,33 @@ def metrics_delta(*, decisions=0, resv=0, prop=0, limit_break=0,
                   cal_ladder_base_decisions=0,
                   cal_ladder_fallbacks=0, ladder_steps=0,
                   supervisor_resumes=0, wheel_occ_hwm=0,
-                  wheel_reslots=0, pallas_fallbacks=0) -> jnp.ndarray:
+                  wheel_reslots=0) -> jnp.ndarray:
     """Build a one-batch delta vector from scalar contributions."""
     rows = [decisions, resv, prop, limit_break, stalls, ring_hwm,
             guard_trips, ingest_drops, rebase_fallbacks,
             server_dropouts, tracker_resyncs, faults_injected,
             cal_ladder_levels_used, cal_ladder_base_decisions,
             cal_ladder_fallbacks, ladder_steps, supervisor_resumes,
-            wheel_occ_hwm, wheel_reslots, pallas_fallbacks]
+            wheel_occ_hwm, wheel_reslots, 0]
     return jnp.stack([jnp.asarray(r, dtype=jnp.int64) for r in rows])
+
+
+def pmax_i64(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """``lax.pmax`` for int64: TPU all-reduces lower only SUM for
+    64-bit integers, so the max runs as two int32 max collectives --
+    the signed high words, then the bias-flipped low words among the
+    shards that tie the max high word ((hi signed, lo unsigned) order
+    IS the int64 order; the kernels_pallas wheel-scan split)."""
+    from jax import lax
+
+    hi = (x >> 32).astype(jnp.int32)
+    lo = ((x & jnp.int64(0xFFFFFFFF))
+          ^ jnp.int64(0x80000000)).astype(jnp.int32)
+    mhi = lax.pmax(hi, axis_name)
+    mlo = lax.pmax(jnp.where(hi == mhi, lo, jnp.int32(-(1 << 31))),
+                   axis_name)
+    return (mhi.astype(jnp.int64) << 32) | \
+        (mlo.astype(jnp.int64) + jnp.int64(1 << 31))
 
 
 def metrics_mesh_reduce(vec: jnp.ndarray, axis_name: str) -> jnp.ndarray:
@@ -157,7 +175,7 @@ def metrics_mesh_reduce(vec: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     totals need no host-side gather (the ROADMAP healthy-path item)."""
     from jax import lax
 
-    return jnp.where(_HWM_MASK, lax.pmax(vec, axis_name),
+    return jnp.where(_HWM_MASK, pmax_i64(vec, axis_name),
                      lax.psum(vec, axis_name))
 
 

@@ -258,7 +258,9 @@ def ledger_mesh_reduce(led: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     disjoint client rows."""
     from jax import lax
 
-    return jnp.where(_LED_MAX_MASK, lax.pmax(led, axis_name),
+    from .device import pmax_i64
+
+    return jnp.where(_LED_MAX_MASK, pmax_i64(led, axis_name),
                      lax.psum(led, axis_name))
 
 

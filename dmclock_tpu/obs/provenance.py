@@ -229,12 +229,14 @@ def prov_mesh_reduce(p: ProvBlock, axis_name: str) -> ProvBlock:
     import jax.numpy as jnp
     from jax import lax
 
+    from .device import pmax_i64
+
     return ProvBlock(
         margin_hist=lax.psum(p.margin_hist, axis_name),
         scal=jnp.where(jnp.asarray(_PS_MAX_MASK),
-                       lax.pmax(p.scal, axis_name),
+                       pmax_i64(p.scal, axis_name),
                        lax.psum(p.scal, axis_name)),
-        last_served=lax.pmax(p.last_served, axis_name))
+        last_served=pmax_i64(p.last_served, axis_name))
 
 
 def prov_from_arrays(margin_hist, scal, last_served) -> ProvBlock:
@@ -467,8 +469,10 @@ def pressure_mesh_reduce(vec, axis_name: str):
     import jax.numpy as jnp
     from jax import lax
 
+    from .device import pmax_i64
+
     return jnp.where(jnp.asarray(_PRESS_MAX_MASK),
-                     lax.pmax(vec, axis_name),
+                     pmax_i64(vec, axis_name),
                      lax.psum(vec, axis_name))
 
 

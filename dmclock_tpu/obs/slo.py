@@ -114,7 +114,9 @@ def window_mesh_reduce(w, axis_name: str):
     import jax.numpy as jnp
     from jax import lax
 
-    return jnp.where(_W_MAX_MASK, lax.pmax(w, axis_name),
+    from .device import pmax_i64
+
+    return jnp.where(_W_MAX_MASK, pmax_i64(w, axis_name),
                      lax.psum(w, axis_name))
 
 

@@ -27,7 +27,7 @@ tracing-off path is a single ``None`` check per call site
 (:func:`span` returns a shared no-op context manager), gated in CI to
 bit-identical decisions and ~zero overhead.  See
 ``docs/OBSERVABILITY.md`` ("Tracing plane") for the schema and
-category taxonomy.
+category set.
 """
 
 from __future__ import annotations
@@ -38,7 +38,7 @@ import time as _walltime
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
-# The fixed category taxonomy (docs/OBSERVABILITY.md).  Every span
+# The fixed category set (docs/OBSERVABILITY.md).  Every span
 # carries exactly one; the exporter validates against this set so a
 # typo'd category fails in CI instead of silently fragmenting the
 # attribution tables.  "compile" is the capacity plane's time axis
@@ -166,7 +166,7 @@ class SpanTracer:
         # attribution tables (the ProfileTimer double-start lesson)
         if cat not in CATEGORIES:
             raise ValueError(f"unknown span category {cat!r} "
-                             f"(taxonomy: {CATEGORIES})")
+                             f"(categories: {CATEGORIES})")
         return _Span(self, name, cat, args or None)
 
     def _push(self, sp: _Span) -> None:
@@ -211,7 +211,7 @@ class SpanTracer:
     def instant(self, name: str, cat: str, **args) -> None:
         if cat not in CATEGORIES:
             raise ValueError(f"unknown span category {cat!r} "
-                             f"(taxonomy: {CATEGORIES})")
+                             f"(categories: {CATEGORIES})")
         self._record(name, cat, self._clock(), 0, 0,
                      len(self._stack()), args or None)
 

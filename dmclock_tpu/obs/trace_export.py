@@ -16,7 +16,7 @@ matched begin/end pair by construction; :func:`validate_chrome_trace`
 checks the stream the way a B/E validator would -- per-tid events must
 nest (every span fully contains its children; partial overlap is a
 corrupted begin/end pairing) with monotone, non-negative timestamps
-and categories from the fixed taxonomy -- and returns per-category
+and categories from the fixed category set -- and returns per-category
 SELF-time sums so CI can gate "category sums ~= wall time"
 (``scripts/ci.sh`` tracing smoke).
 
@@ -123,7 +123,7 @@ def validate_chrome_trace(path: str) -> dict:
     - the envelope is ``{"traceEvents": [...]}`` of "X" events;
     - ``ts``/``dur`` non-negative numbers, ``ts`` monotone
       non-decreasing in file order (the exporter sorts);
-    - every ``cat`` is in the fixed taxonomy (``spans.CATEGORIES``);
+    - every ``cat`` is in the fixed category set (``spans.CATEGORIES``);
     - per ``tid``, events NEST: each event either starts at/after the
       enclosing event's end (a sibling) or ends within it (a child) --
       partial overlap means a corrupted begin/end pairing.
@@ -157,7 +157,7 @@ def validate_chrome_trace(path: str) -> dict:
         cat = ev.get("cat")
         if cat not in CATEGORIES:
             raise ValueError(f"{path}: event {i}: category {cat!r} "
-                             f"not in the taxonomy {CATEGORIES}")
+                             f"not in the span categories {CATEGORIES}")
         if not isinstance(ev.get("name"), str) or not ev["name"]:
             raise ValueError(f"{path}: event {i}: missing name")
         tid = ev.get("tid", 0)

@@ -8,7 +8,7 @@ structured health warnings while a run is still going -- the
 
 - **launch-cadence stall**: no ``dispatch``-category span has
   completed for longer than ``stall_after_s`` while at least one had
-  before -- the serve loop stopped launching (a wedged tunnel, a host
+  before -- the serve loop stopped launching (a wedged device, a host
   deadlock), the failure mode PR-3's guarded retries paper over one
   launch at a time but cannot see across launches.  Streaming-aware:
   an OPEN dispatch/device_compute span (a fused stream chunk

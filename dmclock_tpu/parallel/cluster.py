@@ -30,7 +30,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine import kernels
 from ..obs import device as obsdev
-from ..utils.compat import shard_map
 from ..engine.state import EngineState, init_state
 from .tracker import (BorrowTrackerState, TrackerState,
                       borrow_tracker_prepare, borrow_tracker_track,
@@ -52,8 +51,15 @@ class ClusterState(NamedTuple):
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
+    """A flat ``servers`` mesh over the first ``n_devices`` attached
+    devices (all of them by default); asking for more than are
+    attached is an error, never a smaller mesh."""
     devs = jax.devices()
     if n_devices is not None:
+        if n_devices > len(devs):
+            raise ValueError(f"a {n_devices}-server mesh needs "
+                             f"{n_devices} devices; {len(devs)} "
+                             "attached")
         devs = devs[:n_devices]
     return Mesh(np.array(devs), (SERVER_AXIS,))
 
@@ -289,7 +295,7 @@ def cluster_step(cluster: ClusterState, arrivals: jnp.ndarray,
         out_specs += (spec, P())
     if with_pressure:
         out_specs += (spec, P())
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=out_specs,
@@ -554,7 +560,7 @@ def run_mesh_rounds(cluster: ClusterState, arrivals_seq, cost,
         out_specs += (P(),)
     if with_pressure:
         out_specs += (spec, P())
-    fn = shard_map(shard_fn, mesh=mesh,
+    fn = jax.shard_map(shard_fn, mesh=mesh,
                    in_specs=(spec,) * 7, out_specs=out_specs,
                    check_vma=False)
     outs = fn(cluster.engine, cluster.tracker, cluster.now, arr_s,
@@ -657,7 +663,7 @@ def create_clients(cluster: ClusterState, new_mask: jnp.ndarray,
             e, ops, anticipation_ns=0))(engine)
 
     spec = P(SERVER_AXIS)
-    engine = shard_map(
+    engine = jax.shard_map(
         shard_fn, mesh=mesh, in_specs=(spec,), out_specs=spec,
         check_vma=False)(cluster.engine)
     return cluster._replace(engine=engine)

@@ -59,7 +59,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..engine import fastpath
 from ..engine import stream as stream_mod
 from ..obs import slo as obsslo
-from ..utils.compat import shard_map
 from .cluster import SERVER_AXIS, make_mesh  # noqa: F401 (re-export)
 from .tracker import global_counters_from
 
@@ -410,7 +409,7 @@ def build_mesh_chunk(mesh: Mesh, *, engine: str, epochs: int, m: int,
                       jnp.asarray(faults[4], dtype=bool))
         else:
             faults = None
-        fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+        fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
         (state, cd, cr, vd, vr, hists, ledger, flight, slo, prov,
          outs, merged) = fn(state, cd, cr, vd, vr, epoch0, counts,

@@ -45,7 +45,6 @@ from ..parallel import cluster as CL
 from ..parallel.cluster import SERVER_AXIS, ClusterState, server_round
 from ..parallel.tracker import (BorrowTrackerState, borrow_tracker_track,
                                 global_counters, tracker_track)
-from ..utils.compat import shard_map
 from .faults import FaultPlan, FaultStep, plan_step
 
 
@@ -183,7 +182,7 @@ def _merge_held_metrics(metrics: jnp.ndarray, mesh) -> jnp.ndarray:
     """Mesh-merge the [S, NUM_METRICS] held-view vectors in-graph
     (counters psum, hwm pmax); the result is replicated, one vector."""
     spec = P(SERVER_AXIS)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda m: obsdev.metrics_mesh_reduce(
             obsdev.metrics_combine_axis(m), SERVER_AXIS),
         mesh=mesh, in_specs=(spec,), out_specs=P(),
@@ -282,7 +281,7 @@ def robust_cluster_step(rc: RobustClusterState, arrivals: jnp.ndarray,
     out_specs = (spec,) * 8 + ((P(),) if with_merged else ())
     if with_pressure:
         out_specs += (spec, P())
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(spec,) * 12, out_specs=out_specs,
         check_vma=False)
@@ -435,7 +434,7 @@ def run_mesh_rounds_with_plan(rc: RobustClusterState, arrivals_seq,
                                     delays, dups)
 
     spec = P(SERVER_AXIS)
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(spec,) * 12,
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(spec,) * 12,
                    out_specs=(spec,) * 8, check_vma=False)
     engine, tracker, now, vd, vr, up_prev, met, decs = fn(
         rc.cluster.engine, rc.cluster.tracker, rc.cluster.now, arr_s,

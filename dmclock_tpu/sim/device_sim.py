@@ -60,7 +60,6 @@ from ..engine.fastpath import (_window_heads, calendar_batch,
                                speculate_prefix_batch)
 from ..engine.state import EngineState, init_state
 from ..parallel.cluster import SERVER_AXIS, make_mesh
-from ..utils.compat import shard_map
 from ..parallel.tracker import (TrackerState, global_counters,
                                 init_tracker, tracker_prepare,
                                 tracker_track, tracker_track_counts)
@@ -490,9 +489,8 @@ def device_sim_step(sim: DeviceSim, spec: DeviceSimSpec, mesh: Mesh,
                         # silently under-serve).
                         # The ring-head read forces the XLA rotate:
                         # this whole body runs under vmap (servers),
-                        # which would grid the gridless Pallas kernel
-                        # -- ungridded is all the remote Mosaic
-                        # compiler accepts.
+                        # which would add a grid to the gridless
+                        # Pallas kernel.
                         heads = _window_heads(eng, ring_window(
                             eng, 1, use_pallas=False))
                         batch = speculate_prefix_batch(
@@ -604,7 +602,7 @@ def device_sim_step(sim: DeviceSim, spec: DeviceSimSpec, mesh: Mesh,
     srv = P(SERVER_AXIS)
     rep = P()
     server_ids = jnp.arange(s_total, dtype=jnp.int32)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(srv, srv, rep, srv, srv, srv, rep, rep, srv),
         out_specs=(srv, srv, rep, srv, srv, srv, rep, rep),

@@ -16,6 +16,7 @@ import sys
 
 from .. import models
 from ..obs import DecisionTrace
+from ..utils.compile_cache import enable_compile_cache
 from .config import SimConfig, parse_config_file
 from .harness import Simulation
 
@@ -97,6 +98,7 @@ def main(argv=None) -> int:
                    "USE_PROP_HEAP equivalent; same behavior, faster "
                    "adds at scale)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     if args.use_prop_heap and args.model != "dmclock-native":
         p.error("--use-prop-heap applies to --model dmclock-native")
     # unconditional assignment: in-process callers invoking main()

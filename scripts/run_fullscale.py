@@ -3,8 +3,8 @@
 record (schema v3).
 
 Runs the env-gated minutes-long parity tests with
-``DMCLOCK_FULLSCALE=1`` set, on the virtual CPU mesh (same backend
-selection as the test suite): the 100x100 acceptance-config sim parity
+``DMCLOCK_FULLSCALE=1`` set, on the virtual CPU mesh the test suite
+pins: the 100x100 acceptance-config sim parity
 (``tests/test_sim_tpu_fullscale.py``) and the 8x1000-client cluster
 parity for both tracker policies
 (``tests/test_cluster_realism.py::test_cluster_parity_fullscale``).
@@ -12,10 +12,13 @@ Kept as a separate entry point so the default ``pytest tests/`` stays
 fast; ``scripts/ci.sh`` invokes this after the main suite.
 
 ``--record FILE`` additionally writes the MULTICHIP record in
-**schema v3**: the v1 fields (``n_devices``/``rc``/``ok``/``tail``
+**schema v3**, from children that run on the ATTACHED devices (this
+parent never imports jax, so a child can hold the chips; with too few
+devices the children fail and the record says ``ok: false``): the v1
+fields (``n_devices``/``rc``/``ok``/``tail``
 from the QoS dryrun, unchanged) plus the v2 ``mesh`` block -- the
 mesh serving plane's aggregate-throughput trajectory from one
-``bench.py --mode mesh`` run on the forced host mesh: aggregate and
+``bench.py --mode mesh`` run (TPU only): aggregate and
 per-shard dec/s, counter-exchange bytes per epoch, and the sync
 cadence -- plus the v3 ``rebalance`` block (``--rebalance on``): the
 shard-rebalancing A/B row (placement mode, migration count + log,
@@ -90,8 +93,7 @@ def load_multichip(path: str) -> dict:
 
 def _dryrun(n_devices: int):
     """The v1 QoS dryrun block: run ``dryrun_multichip`` in a child
-    (its own device forcing must precede backend init) and keep its
-    stdout tail."""
+    on the attached devices and keep its stdout tail."""
     proc = subprocess.run(
         [sys.executable, "-c",
          f"import __graft_entry__ as g; g.dryrun_multichip({n_devices})"],
@@ -106,7 +108,7 @@ def _mesh_trajectory(n_devices: int, clients: int, sync: int,
                      fault_plan: str = "none",
                      rebalance: str = "off"):
     """The v2 mesh block + v3 rebalance block: one ``bench.py --mode
-    mesh`` run on a forced host mesh; the bench JSON line carries the
+    mesh`` run on the attached chips; the bench JSON line carries the
     full mesh row (aggregate + per-shard dec/s, counter-exchange
     accounting, and -- when ``fault_plan`` is a parseable spec -- the
     chaos counters: plan tag + per-shard dropout/resync counts) and,
@@ -118,8 +120,7 @@ def _mesh_trajectory(n_devices: int, clients: int, sync: int,
          "--counter-sync-every", str(sync),
          "--fault-plan", fault_plan,
          "--rebalance", rebalance],
-        cwd=REPO, capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        cwd=REPO, capture_output=True, text=True)
     for line in reversed((proc.stdout or "").splitlines()):
         line = line.strip()
         if line.startswith("{"):

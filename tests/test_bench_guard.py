@@ -66,7 +66,7 @@ def test_fallback_newest_annotated_not_judged(monkeypatch, capsys,
     hist = write_history(tmp_path, [("tpu0", 40e6), ("tpu0", 45e6)])
     (hist / "bench_2000.json").write_text(json.dumps(
         {"platform": "cpu", "device": "CpuDevice(id=0)",
-         "fallback": True, "backend_error": "RuntimeError: tunnel",
+         "fallback": True, "backend_error": "RuntimeError: backend",
          "workloads": {"serve": {"dps": 0.2e6}}}))
     rc, out = run_guard(monkeypatch, capsys, hist)
     assert rc == 0

@@ -348,6 +348,23 @@ class TestRoofline:
                               **self.PK)
         assert out["bound_class"] == "unknown"
 
+    def test_peaks_keyed_by_device_kind(self):
+        """A v5e reports device_kind "TPU v5 lite" and must get the
+        v5e peaks; a kind not in the table is an error, not a
+        made-up default."""
+        class Dev:
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        v5e = obscap.device_peaks(Dev("TPU v5 lite"))
+        assert (v5e["peak_flops"], v5e["peak_bytes_per_s"]) == \
+            (197e12, 819e9)
+        for kind in ("TPU v99", "v5e", "TPU v5 lite "):
+            with pytest.raises(KeyError, match="no roofline peaks"):
+                obscap.device_peaks(Dev(kind))
+        assert obscap.device_peaks()["label"] == \
+            jax.devices()[0].device_kind
+
     def test_classify_bench_row_joins_spans(self):
         row = {"cost_analysis": {"flops": 1e9,
                                  "bytes_accessed": 1e9},

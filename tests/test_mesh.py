@@ -295,9 +295,10 @@ class TestCollectiveSkipping:
                                   np.asarray(jax.device_get(b)))
 
     def test_collective_execution_counts(self):
-        """The structural gate: flat executes 2E+2 collectives (cd/cr
-        psum per epoch + the final window-merge psum/pmax); grouped
-        executes 2*(E/K)+2 -- and the a1-a8 identity
+        """The structural gate: flat executes 2E+3 collectives (cd/cr
+        psum per epoch + the final window-merge psum and its int64
+        max, two int32 pmax passes on TPU); grouped executes
+        2*(E/K)+3 -- and the a1-a8 identity
         flat - grouped(K=E) == (E-1) * (grouped(K=E/2) - grouped(K=E))
         pins that the difference is exactly the per-epoch pair."""
         E = 8
@@ -306,9 +307,9 @@ class TestCollectiveSkipping:
             fn, args = self._chunk_fn(2, E, K, skip)
             jx = jax.make_jaxpr(fn)(*args)
             counts[K] = _collective_execs(jx.jaxpr)
-        assert counts[1] == 2 * E + 2, counts
-        assert counts[4] == 2 * (E // 4) + 2, counts
-        assert counts[8] == 2 * (E // 8) + 2, counts
+        assert counts[1] == 2 * E + 3, counts
+        assert counts[4] == 2 * (E // 4) + 3, counts
+        assert counts[8] == 2 * (E // 8) + 3, counts
         assert counts[1] - counts[8] == \
             (E - 1) * (counts[4] - counts[8])
 
@@ -461,7 +462,7 @@ class TestShardPlanning:
                                                      monkeypatch):
         """The shard count FALLS OUT of the client target: with a
         budget that fits ~B clients/shard, planning N clients yields
-        ceil(N / max_clients) shards (capped at the device count)."""
+        ceil(N / max_clients) shards."""
         import bench
 
         from dmclock_tpu.obs import capacity as obscap
@@ -489,14 +490,24 @@ class TestShardPlanning:
         assert plan["shards_planned"] is None
         assert plan["n_shards"] == len(jax.devices())
 
-    def test_explicit_shards_capped_at_devices(self, monkeypatch):
+    def test_oversubscribed_shards_raise(self, monkeypatch):
+        """More shards than attached devices is an error, never a
+        quietly smaller mesh: the bench plan, the rebalance row's
+        mesh, and make_mesh itself."""
         import bench
 
+        from dmclock_tpu.parallel import mesh as mesh_mod
+
         monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES", "0")
-        plan = bench.plan_mesh_shards(
-            1000, len(jax.devices()) + 7, ring=10, engine="prefix",
-            m=2, k=16)
-        assert plan["n_shards"] == len(jax.devices())
+        too_many = len(jax.devices()) + 7
+        with pytest.raises(ValueError, match="devices attached"):
+            bench.plan_mesh_shards(1000, too_many, ring=10,
+                                   engine="prefix", m=2, k=16)
+        with pytest.raises(ValueError, match="devices attached"):
+            bench.bench_mesh_rebalance(n_shards=too_many)
+        with pytest.raises(ValueError, match="attached"):
+            mesh_mod.make_mesh(too_many)
+        assert mesh_mod.make_mesh(2).devices.size == 2
 
 
 class TestMeshRoundsComposition:

@@ -483,7 +483,7 @@ class TestQueueGuardedCommit:
             def wrapped(*a):
                 if fails["n"] > 0:
                     fails["n"] -= 1
-                    raise OSError("tunnel wedged")
+                    raise OSError("device wedged")
                 return fn(*a)
             return wrapped
 

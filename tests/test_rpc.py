@@ -352,6 +352,26 @@ class TestLoopback:
             # conservation: every admitted op is queued exactly once
             assert srv.queue_depth() == c["admitted_ops"]
 
+    def test_oversized_notify_splits_into_frames(self):
+        """Per-client verdicts at 100k clients overflow one frame: the
+        batch goes out as numbered parts, each a legal frame, whose
+        verdicts concatenate back in order."""
+        from dmclock_tpu.net.server import notify_payloads
+
+        rows = [{"cid": c, "verdict": "conformant", "rate": c * 0.5}
+                for c in range(60_000)]
+        obj = {"b": 3, "boundary": 2, "decisions": 9, "verdicts": rows}
+        payloads = notify_payloads(obj)
+        assert len(payloads) > 1
+        parts = [framing.unpack(framing.frame(p)[4:])[1][0]
+                 for p in payloads]
+        assert [p["part"] for p in parts] == list(range(len(parts)))
+        assert {p["parts"] for p in parts} == {len(parts)}
+        assert all(p["decisions"] == 9 for p in parts)
+        assert [r for p in parts for r in p["verdicts"]] == rows
+        small = {"b": 0, "verdicts": rows[:3]}
+        assert notify_payloads(small) == [framing.pack_notify(small)]
+
     def test_notify_reaches_subscribers(self):
         with IngestServer(4, waves=4, port=0) as srv:
             got = []
@@ -424,6 +444,25 @@ class TestLoadgen:
             assert srv.counters["admitted_reqs"] == 16
         finally:
             srv.stop()
+
+
+    def test_spawn_worker_imports_stay_off_jax(self):
+        """A loadgen spawn worker must never import jax: the server
+        process holds the chip, and a worker that reached for it
+        would fail or hang.  Import exactly what a worker runs."""
+        code = ("import sys\n"
+                "sys.path.insert(0, 'scripts')\n"
+                "import loadgen\n"
+                "from dmclock_tpu.net import client, faults\n"
+                "loadgen.worker_schedule(3, 0, workers=2, requests=4, "
+                "n_clients=8, max_nops=3)\n"
+                "bad = sorted(m for m in sys.modules "
+                "if m == 'jax' or m.startswith(('jax.', 'jaxlib')))\n"
+                "assert not bad, bad\n")
+        proc = subprocess.run([sys.executable, "-c", code],
+                              cwd=str(REPO), capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
 
 
 # ----------------------------------------------------------------------

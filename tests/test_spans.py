@@ -65,9 +65,9 @@ class TestSpanTracer:
     def test_unknown_category_rejected(self):
         # ValueError, not assert: must survive PYTHONOPTIMIZE
         tr = S.SpanTracer()
-        with pytest.raises(ValueError, match="taxonomy"):
+        with pytest.raises(ValueError, match="categories"):
             tr.span("x", "not-a-category")
-        with pytest.raises(ValueError, match="taxonomy"):
+        with pytest.raises(ValueError, match="categories"):
             tr.instant("x", "also-wrong")
 
     def test_null_guard_is_noop(self):
@@ -197,7 +197,7 @@ class TestChromeExport:
         json.dump({"traceEvents": [
             {"name": "x", "cat": "mystery", "ph": "X", "ts": 0,
              "dur": 1, "pid": 0, "tid": 0}]}, open(path, "w"))
-        with pytest.raises(ValueError, match="taxonomy"):
+        with pytest.raises(ValueError, match="categories"):
             TE.validate_chrome_trace(path)
 
     def test_validator_rejects_partial_overlap(self, tmp_path):
@@ -441,7 +441,7 @@ class TestWatchdog:
     def test_wedged_launch_still_warns(self):
         # the suppression is BOUNDED: a launch the runtime wedged
         # INSIDE (an open device_wait older than in_flight_max_s)
-        # must stop suppressing -- the wedged tunnel is the original
+        # must stop suppressing -- a wedged launch is the original
         # failure mode the stall check exists for
         clock, adv = make_clock()
         tr = S.SpanTracer(clock_ns=clock)

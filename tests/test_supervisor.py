@@ -10,9 +10,15 @@ the zero-cost-when-off gate (supervisor-wrapped == bare runner,
 bit-identical), the degradation ladder, and bounded restarts."""
 
 import dataclasses
+import os
+import subprocess
+import sys
+from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
+from jax._src import xla_bridge
 
 from dmclock_tpu.obs import device as obsdev
 from dmclock_tpu.robust import host_faults as HF
@@ -20,6 +26,8 @@ from dmclock_tpu.robust import supervisor as SV
 from dmclock_tpu.robust.guarded import (LADDER_RUNGS,
                                         DegradationLadder)
 from dmclock_tpu.utils import checkpoint as ckpt_mod
+
+REPO = Path(__file__).resolve().parent.parent
 
 # one small job per engine/fast-path combination; module-level cache
 # of the bare reference runs (each parametrized case reuses its
@@ -280,7 +288,7 @@ class TestDegradationLadder:
         def flaky(state, now, **kw):
             calls.append(kw["select_impl"])
             if kw["select_impl"] == "radix":
-                raise TimeoutError("wedged tunnel")
+                raise TimeoutError("wedged device")
             return real(state, now, **kw)
 
         monkeypatch.setattr(SV, "run_epoch_guarded", flaky)
@@ -304,7 +312,7 @@ class TestDegradationLadder:
         restarts from the checkpoint like a kill, bounded by
         max_restarts."""
         def dead(*_a, **_k):
-            raise TimeoutError("tunnel never came back")
+            raise TimeoutError("device never came back")
 
         monkeypatch.setattr(SV, "run_epoch_guarded", dead)
         with pytest.raises(SV.SupervisorGaveUp):
@@ -338,6 +346,37 @@ class TestDegradationLadder:
                 for s in res.ladder_steps] == \
             [(s["knob"], s["from"], s["to"])
              for s in ref.ladder_steps]
+
+
+class TestOneProcessPerChip:
+    """A chip belongs to one process: the spawn-mode parent must stay
+    off every JAX backend, and refuses to spawn while it holds one."""
+
+    def test_spawn_parent_never_touches_a_backend(self, tmp_path):
+        code = (
+            "from jax._src import xla_bridge\n"
+            "from dmclock_tpu.robust import supervisor as SV\n"
+            "job = SV.EpochJob(n=16, epochs=2, ckpt_every=1)\n"
+            f"res = SV.run_supervised(job, {str(tmp_path / 'wd')!r}, "
+            "mode='spawn')\n"
+            "assert res.decisions > 0\n"
+            "assert not xla_bridge.backends_are_initialized()\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+        proc = subprocess.run([sys.executable, "-c", code], env=env,
+                              cwd=str(REPO), capture_output=True,
+                              text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
+    def test_spawn_refused_while_holding_an_accelerator(
+            self, tmp_path, monkeypatch):
+        monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                            lambda: True)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="trampoline"):
+            SV.run_supervised(SV.EpochJob(n=16, epochs=2), tmp_path,
+                              mode="spawn")
+        assert not (tmp_path / SV.JOB_FILE).exists()
 
 
 @pytest.mark.slow

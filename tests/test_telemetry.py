@@ -468,12 +468,33 @@ class TestFlightRecorder:
 # ----------------------------------------------------------------------
 
 class TestMeshReduce:
-    def test_hist_and_ledger_mesh_reduce(self):
+    def test_pmax_i64_is_the_int64_max(self):
+        """The two-int32-pass max (TPU lowers only SUM for int64
+        all-reduces) equals the int64 max over the shards, across
+        sign, high-word ties, and the low word's top bit."""
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 (virtual) devices")
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from dmclock_tpu.utils.compat import shard_map
+        from dmclock_tpu.obs import device as obsdev
+
+        mesh = Mesh(np.array(jax.devices()[:4]), ("servers",))
+        x = np.array([
+            [-1, 5, (1 << 40) + 7, -(1 << 62), (1 << 32) + 0x80000000],
+            [-2, 5, (1 << 40) + 0xFFFFFFFF, -(1 << 62) + 1, 1 << 32],
+            [-(1 << 63), 4, 1 << 40, -(1 << 63), (1 << 32) + 1],
+            [-3, -5, (1 << 40) + 0x80000000, -5, 0x7FFFFFFF],
+        ], dtype=np.int64)
+        got = jax.shard_map(
+            lambda v: obsdev.pmax_i64(v[0], "servers"), mesh=mesh,
+            in_specs=P("servers"), out_specs=P(),
+            check_vma=False)(jnp.asarray(x))
+        assert np.array_equal(np.asarray(got), x.max(axis=0))
+
+    def test_hist_and_ledger_mesh_reduce(self):
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 (virtual) devices")
+        from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()[:4]), ("servers",))
         hs = jnp.stack([obshist.hist_zero().at[0, i].add(i + 1)
@@ -487,7 +508,7 @@ class TestMeshReduce:
             return (obshist.hist_mesh_reduce(h[0], "servers"),
                     obshist.ledger_mesh_reduce(l[0], "servers"))
 
-        mh, ml = shard_map(
+        mh, ml = jax.shard_map(
             merge, mesh=mesh,
             in_specs=(P("servers"), P("servers")),
             out_specs=(P(), P()))(hs, ls)
