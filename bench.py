@@ -2794,13 +2794,6 @@ def main() -> None:
             obshist.publish_hists(default_registry(),
                                   np.asarray(hb, dtype=np.int64),
                                   labels={"workload": wl})
-        if "spans" in row:
-            # span-derived dispatch-tax gauges ride the same scrape
-            # endpoint as the histogram families
-            from dmclock_tpu.obs import (default_registry,
-                                         publish_span_gauges)
-            publish_span_gauges(default_registry(), row["spans"],
-                                labels={"workload": wl})
         if "provenance" in row:
             # per-workload provenance verdicts as labelled gauges on
             # the same scrape endpoint (dmclock_provenance_* /

@@ -78,6 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.timebase import MAX_TAG, MIN_TAG
 from ..obs import device as obsdev
+from ..obs.compile_plane import phase_scope
 from ..obs import flight as obsflight
 from ..obs import histograms as obshist
 from ..obs import provenance as obsprov
@@ -193,7 +194,7 @@ def _rotate_rows_pallas(ring, q0, wsize: int, *, q0t=None,
     call = pl.pallas_call(
         functools.partial(_rotate_kernel, q=q),
         out_shape=jax.ShapeDtypeStruct((chunk, 2 * q), jnp.int32),
-        interpret=interpret)
+        interpret=interpret, name="ring_rotate")
     # slice each chunk to the window BEFORE concatenating: the full
     # rotated ring is never materialized in HBM
     outs = [call(q0t[c:c + chunk], i32[c:c + chunk])
@@ -244,15 +245,17 @@ def ring_window(state: EngineState, m: int,
     # the Pallas path needs a full lane tile (2q >= 128 int32 lanes)
     if use_pallas is None:
         use_pallas = on_tpu() and q >= 64
-    if use_pallas:
-        n = q0.shape[0]
-        q0t = _tile_shifts(q0, q, n + ((-n) % _rot_chunk(q)))
-        rot = functools.partial(_rotate_rows_pallas, q0=q0,
-                                wsize=wsize, q0t=q0t)
-    else:
-        rot = functools.partial(_rotate_rows_xla, q0=q0, wsize=wsize)
-    return RingWindow(arr=rot(state.q_arrival), cost=rot(state.q_cost),
-                      q0=q0)
+    with phase_scope("window"):
+        if use_pallas:
+            n = q0.shape[0]
+            q0t = _tile_shifts(q0, q, n + ((-n) % _rot_chunk(q)))
+            rot = functools.partial(_rotate_rows_pallas, q0=q0,
+                                    wsize=wsize, q0t=q0t)
+        else:
+            rot = functools.partial(_rotate_rows_xla, q0=q0,
+                                    wsize=wsize)
+        return RingWindow(arr=rot(state.q_arrival),
+                          cost=rot(state.q_cost), q0=q0)
 
 
 def _window_rows(state: EngineState, window: RingWindow, depth: int):
@@ -262,18 +265,19 @@ def _window_rows(state: EngineState, window: RingWindow, depth: int):
     take_along_axis lowers to a serializing gather (measured 20x
     slower)."""
     wsize = window.arr.shape[0]
-    off = jnp.remainder(state.q_head - window.q0,
-                        state.ring_capacity).astype(jnp.int32)
     arr_rows, cost_rows = [], []
-    for d in range(depth):
-        narr = window.arr[min(d, wsize - 1)]
-        ncost = window.cost[min(d, wsize - 1)]
-        for j in range(d + 1, wsize):
-            pick = off == j - d
-            narr = jnp.where(pick, window.arr[j], narr)
-            ncost = jnp.where(pick, window.cost[j], ncost)
-        arr_rows.append(narr)
-        cost_rows.append(ncost)
+    with phase_scope("window"):
+        off = jnp.remainder(state.q_head - window.q0,
+                            state.ring_capacity).astype(jnp.int32)
+        for d in range(depth):
+            narr = window.arr[min(d, wsize - 1)]
+            ncost = window.cost[min(d, wsize - 1)]
+            for j in range(d + 1, wsize):
+                pick = off == j - d
+                narr = jnp.where(pick, window.arr[j], narr)
+                ncost = jnp.where(pick, window.cost[j], ncost)
+            arr_rows.append(narr)
+            cost_rows.append(ncost)
     return arr_rows, cost_rows
 
 
@@ -607,166 +611,168 @@ def _unified_prefix(state: EngineState, now, k: int, *,
         heads = (heads.arr, heads.cost)
     arr_rows, cost_rows = _heads_rows(heads, chain_depth)
 
-    cls, key = _classify(state, now, allow)
-    chain = _chain_serve(state, now, arr_rows, cost_rows, cls, allow,
-                         anticipation_ns)
+    with phase_scope("select"):
+        cls, key = _classify(state, now, allow)
+        chain = _chain_serve(state, now, arr_rows, cost_rows, cls, allow,
+                             anticipation_ns)
 
-    is_cand = cls != CLS_NONE
-    # --- packed rebase over two key spaces: reservation tags
-    # (class 0) and effective proportion tags (classes 1/2).
-    # PER-CLASS rebase origins: each class's minimum entry rebases to
-    # the bias, so position 0 of the sort is always in-window and a
-    # nonempty candidate set always commits >= 1 unit (guaranteed
-    # progress), whatever the spread between the classes' key spaces.
-    # _EXIT_BIAS reserves the window's low end for exits that land
-    # BELOW their class origin (e.g. a constraint serve re-entering
-    # weight space under the ready minimum): within the bias they
-    # rebase exactly; further below they clamp to 0, which only
-    # shortens the prefix -- conservative, never inexact.
-    def class_min(m):
-        return jnp.min(jnp.where(m, key, KEY_INF))
+        is_cand = cls != CLS_NONE
+        # --- packed rebase over two key spaces: reservation tags
+        # (class 0) and effective proportion tags (classes 1/2).
+        # PER-CLASS rebase origins: each class's minimum entry rebases to
+        # the bias, so position 0 of the sort is always in-window and a
+        # nonempty candidate set always commits >= 1 unit (guaranteed
+        # progress), whatever the spread between the classes' key spaces.
+        # _EXIT_BIAS reserves the window's low end for exits that land
+        # BELOW their class origin (e.g. a constraint serve re-entering
+        # weight space under the ready minimum): within the bias they
+        # rebase exactly; further below they clamp to 0, which only
+        # shortens the prefix -- conservative, never inexact.
+        def class_min(m):
+            return jnp.min(jnp.where(m, key, KEY_INF))
 
-    kresv = class_min(cls == CLS_RESV)
-    kprop1 = class_min(cls == CLS_WEIGHT)
-    kprop2 = class_min(cls == CLS_LB)
+        kresv = class_min(cls == CLS_RESV)
+        kprop1 = class_min(cls == CLS_WEIGHT)
+        kprop2 = class_min(cls == CLS_LB)
 
-    def origin_of(c):
-        return jnp.where(c == CLS_RESV, kresv,
-                         jnp.where(c == CLS_WEIGHT, kprop1, kprop2))
+        def origin_of(c):
+            return jnp.where(c == CLS_RESV, kresv,
+                             jnp.where(c == CLS_WEIGHT, kprop1, kprop2))
 
-    krel = jnp.clip(key - origin_of(cls) + _EXIT_BIAS, 0,
-                    jnp.int64(_KEY_CLAMP))
+        krel = jnp.clip(key - origin_of(cls) + _EXIT_BIAS, 0,
+                        jnp.int64(_KEY_CLAMP))
 
-    # order rebased like the keys: creation indices grow without bound,
-    # so the 28-bit pack must be of the spread, not the absolute value
-    omin = jnp.min(jnp.where(is_cand, state.order, jnp.int64(1) << 62))
-    o64 = state.order - omin
-    omax = jnp.max(jnp.where(is_cand, state.order, omin))
-    # the cost guard masks to real candidates: an oversized cost on an
-    # inactive/non-candidate row must not disable the fastpath forever
-    cost_ok = jnp.max(jnp.where(is_cand, state.head_cost, 0)) \
-        < (jnp.int64(1) << 31)
-    guards_ok = (omax - omin < _ORDER_LIMIT) & cost_ok
+        # order rebased like the keys: creation indices grow without bound,
+        # so the 28-bit pack must be of the spread, not the absolute value
+        omin = jnp.min(jnp.where(is_cand, state.order, jnp.int64(1) << 62))
+        o64 = state.order - omin
+        omax = jnp.max(jnp.where(is_cand, state.order, omin))
+        # the cost guard masks to real candidates: an oversized cost on an
+        # inactive/non-candidate row must not disable the fastpath forever
+        cost_ok = jnp.max(jnp.where(is_cand, state.head_cost, 0)) \
+            < (jnp.int64(1) << 31)
+        guards_ok = (omax - omin < _ORDER_LIMIT) & cost_ok
 
-    pk_dense = jnp.where(is_cand, _pack(cls, krel, o64),
-                         jnp.int64(KEY_INF))
+        pk_dense = jnp.where(is_cand, _pack(cls, krel, o64),
+                             jnp.int64(KEY_INF))
 
-    # exit keys in the same packed space.  Clamping an exit low (past
-    # the bias below its class origin) only shortens the prefix --
-    # conservative, never inexact; clamping high (_KEY_HI, above the
-    # entry clamp) preserves ``exit > boundary`` for every committable
-    # boundary, which is strictly in-window.
-    ekrel = jnp.clip(chain.exit_key - origin_of(chain.exit_cls)
-                     + _EXIT_BIAS, 0, jnp.int64(_KEY_HI))
-    epk = jnp.where(chain.exit_cls == CLS_NONE, jnp.int64(KEY_INF),
-                    _pack(chain.exit_cls, ekrel, o64))
+        # exit keys in the same packed space.  Clamping an exit low (past
+        # the bias below its class origin) only shortens the prefix --
+        # conservative, never inexact; clamping high (_KEY_HI, above the
+        # entry clamp) preserves ``exit > boundary`` for every committable
+        # boundary, which is strictly in-window.
+        ekrel = jnp.clip(chain.exit_key - origin_of(chain.exit_cls)
+                         + _EXIT_BIAS, 0, jnp.int64(_KEY_HI))
+        epk = jnp.where(chain.exit_cls == CLS_NONE, jnp.int64(KEY_INF),
+                        _pack(chain.exit_cls, ekrel, o64))
 
-    iota = jnp.arange(key.shape[0], dtype=jnp.int32)
-    kk = min(k, key.shape[0])
+        iota = jnp.arange(key.shape[0], dtype=jnp.int32)
+        kk = min(k, key.shape[0])
 
-    def trim(a, fill):
-        a = a[:kk]
-        if kk < k:      # k beyond the population: sentinel padding
-            a = jnp.concatenate(
-                [a, jnp.full((k - kk,), fill, dtype=a.dtype)])
-        return a
+        def trim(a, fill):
+            a = a[:kk]
+            if kk < k:      # k beyond the population: sentinel padding
+                a = jnp.concatenate(
+                    [a, jnp.full((k - kk,), fill, dtype=a.dtype)])
+            return a
 
-    if select_impl == "radix":
-        pks, idxs, rpk, costs, lens = _select_radix(
-            pk_dense, iota, epk, state.head_cost.astype(jnp.int32),
-            chain.length if chain_depth > 1 else None, k, kk)
-    elif chain_depth == 1:
-        pks, idxs, rpk, costs = lax.sort(
-            (pk_dense, iota, epk,
-             state.head_cost.astype(jnp.int32)), num_keys=1)
-        lens = jnp.ones((k,), dtype=jnp.int32)
-        pks, idxs = trim(pks, KEY_INF), trim(idxs, -1)
-        rpk, costs = trim(rpk, KEY_INF), trim(costs, 0)
-    else:
-        pks, idxs, rpk, costs, lens = lax.sort(
-            (pk_dense, iota, epk,
-             state.head_cost.astype(jnp.int32), chain.length),
-            num_keys=1)
-        lens = trim(lens, 0)
-        pks, idxs = trim(pks, KEY_INF), trim(idxs, -1)
-        rpk, costs = trim(rpk, KEY_INF), trim(costs, 0)
+        if select_impl == "radix":
+            pks, idxs, rpk, costs, lens = _select_radix(
+                pk_dense, iota, epk, state.head_cost.astype(jnp.int32),
+                chain.length if chain_depth > 1 else None, k, kk)
+        elif chain_depth == 1:
+            pks, idxs, rpk, costs = lax.sort(
+                (pk_dense, iota, epk,
+                 state.head_cost.astype(jnp.int32)), num_keys=1)
+            lens = jnp.ones((k,), dtype=jnp.int32)
+            pks, idxs = trim(pks, KEY_INF), trim(idxs, -1)
+            rpk, costs = trim(rpk, KEY_INF), trim(costs, 0)
+        else:
+            pks, idxs, rpk, costs, lens = lax.sort(
+                (pk_dense, iota, epk,
+                 state.head_cost.astype(jnp.int32), chain.length),
+                num_keys=1)
+            lens = trim(lens, 0)
+            pks, idxs = trim(pks, KEY_INF), trim(idxs, -1)
+            rpk, costs = trim(rpk, KEY_INF), trim(costs, 0)
 
-    # exclusive cumulative min of exit keys over the sorted order
-    cm = lax.associative_scan(jnp.minimum, rpk)
-    cm_excl = jnp.concatenate(
-        [jnp.full((1,), jnp.int64(KEY_INF), dtype=jnp.int64), cm[:-1]])
+        # exclusive cumulative min of exit keys over the sorted order
+        cm = lax.associative_scan(jnp.minimum, rpk)
+        cm_excl = jnp.concatenate(
+            [jnp.full((1,), jnp.int64(KEY_INF), dtype=jnp.int64), cm[:-1]])
 
-    in_window = ((pks >> 60) < CLS_NONE) & \
-        (((pks >> 28) & _KEY_HI) < _KEY_CLAMP)
-    ok_q = in_window & (cm_excl > pks)
-    count_units = jnp.where(jnp.all(ok_q), jnp.int32(k),
-                            jnp.argmax(~ok_q).astype(jnp.int32))
-    count_units = jnp.where(guards_ok, count_units, jnp.int32(0))
-    if max_count is not None:
-        assert chain_depth == 1, \
-            "max_count caps decisions; only supported at chain_depth=1"
-        count_units = jnp.minimum(count_units, jnp.int32(max_count))
+        in_window = ((pks >> 60) < CLS_NONE) & \
+            (((pks >> 28) & _KEY_HI) < _KEY_CLAMP)
+        ok_q = in_window & (cm_excl > pks)
+        count_units = jnp.where(jnp.all(ok_q), jnp.int32(k),
+                                jnp.argmax(~ok_q).astype(jnp.int32))
+        count_units = jnp.where(guards_ok, count_units, jnp.int32(0))
+        if max_count is not None:
+            assert chain_depth == 1, \
+                "max_count caps decisions; only supported at chain_depth=1"
+            count_units = jnp.minimum(count_units, jnp.int32(max_count))
 
-    j = jnp.arange(k, dtype=jnp.int32)
-    served = j < count_units
-    cls_s = (pks >> 60).astype(jnp.int32)   # >= CLS_NONE on sentinels
+        j = jnp.arange(k, dtype=jnp.int32)
+        served = j < count_units
+        cls_s = (pks >> 60).astype(jnp.int32)   # >= CLS_NONE on sentinels
 
-    # provenance margins (obs.provenance): at the instant unit j
-    # commits, the candidate set is {entries j+1..} plus the re-entry
-    # exit keys of the already-served prefix p < j -- so the EXACT
-    # runner-up is min(pks[j+1], cm_excl[j]), both already
-    # materialized.  The >> 28 strips the order bits (packed key =
-    # cls<<60 | rebased-ns<<28 | order): a same-class margin is the
-    # tag distance in ns; a cross-class one carries the class step
-    # (>= 2^32 ns -- "the phase ladder, not the tag, decided").  -1 =
-    # no runner-up existed (sole candidate).  Dead code unless a
-    # provenance/flight consumer reads it (XLA DCE).
-    nxt = jnp.concatenate(
-        [pks[1:], jnp.full((1,), jnp.int64(KEY_INF))])
-    runner = jnp.minimum(nxt, cm_excl)
-    margin_s = jnp.where(served & (runner < jnp.int64(KEY_INF)),
-                         (runner - pks) >> 28, jnp.int64(-1))
-    if chain_depth == 1:
-        count = count_units
-    else:
-        count = jnp.sum(jnp.where(served, lens, 0)).astype(jnp.int32)
+        # provenance margins (obs.provenance): at the instant unit j
+        # commits, the candidate set is {entries j+1..} plus the re-entry
+        # exit keys of the already-served prefix p < j -- so the EXACT
+        # runner-up is min(pks[j+1], cm_excl[j]), both already
+        # materialized.  The >> 28 strips the order bits (packed key =
+        # cls<<60 | rebased-ns<<28 | order): a same-class margin is the
+        # tag distance in ns; a cross-class one carries the class step
+        # (>= 2^32 ns -- "the phase ladder, not the tag, decided").  -1 =
+        # no runner-up existed (sole candidate).  Dead code unless a
+        # provenance/flight consumer reads it (XLA DCE).
+        nxt = jnp.concatenate(
+            [pks[1:], jnp.full((1,), jnp.int64(KEY_INF))])
+        runner = jnp.minimum(nxt, cm_excl)
+        margin_s = jnp.where(served & (runner < jnp.int64(KEY_INF)),
+                             (runner - pks) >> 28, jnp.int64(-1))
+        if chain_depth == 1:
+            count = count_units
+        else:
+            count = jnp.sum(jnp.where(served, lens, 0)).astype(jnp.int32)
 
-    # commit: dense membership is ``packed(key) <= packed boundary``
-    # (packed keys are unique).  The boundary pk[count-1] is read as a
-    # masked max over the sorted prefix, not a dynamic gather --
-    # scalar gathers from vectors serialize on this stack (PROFILE.md
-    # findings 4/8).
-    boundary = jnp.max(jnp.where(served, pks, jnp.int64(-1)))
-    sel = pk_dense <= boundary
-    new_state = _commit_chains(state, sel, chain)
+    with phase_scope("commit"):
+        # commit: dense membership is ``packed(key) <= packed boundary``
+        # (packed keys are unique).  The boundary pk[count-1] is read as a
+        # masked max over the sorted prefix, not a dynamic gather --
+        # scalar gathers from vectors serialize on this stack (PROFILE.md
+        # findings 4/8).
+        boundary = jnp.max(jnp.where(served, pks, jnp.int64(-1)))
+        sel = pk_dense <= boundary
+        new_state = _commit_chains(state, sel, chain)
 
-    # stored-flag parity (promote loop, reference :1135-1144): every
-    # weight-phase (class >= 1 entry) decision promotes current heads
-    # with limit <= now.  Classes sort ascending, so the LAST committed
-    # unit has the batch's max class: if it is >= 1, its entry decision
-    # ran the batch's final promote pass, and the only head that pass
-    # never saw is the one its own chain popped into place.  With no
-    # class >= 1 unit committed no promote pass ran, so the flags stay
-    # untouched (pops still clear them via _commit_chains).
-    sel_last = j == count_units - 1
-    cls_last = jnp.max(jnp.where(sel_last, cls_s, -1))
-    last_client = jnp.max(jnp.where(sel_last, idxs, -1))
-    do_promote = (count_units > 0) & (cls_last >= CLS_WEIGHT)
-    has_req_after = new_state.active & (new_state.depth > 0)
-    promoted = new_state.head_ready | \
-        (has_req_after & (new_state.head_limit <= now))
-    promoted = promoted & (
-        jnp.arange(state.capacity, dtype=jnp.int32) != last_client)
-    new_state = new_state._replace(head_ready=jnp.where(
-        do_promote, promoted, new_state.head_ready))
+        # stored-flag parity (promote loop, reference :1135-1144): every
+        # weight-phase (class >= 1 entry) decision promotes current heads
+        # with limit <= now.  Classes sort ascending, so the LAST committed
+        # unit has the batch's max class: if it is >= 1, its entry decision
+        # ran the batch's final promote pass, and the only head that pass
+        # never saw is the one its own chain popped into place.  With no
+        # class >= 1 unit committed no promote pass ran, so the flags stay
+        # untouched (pops still clear them via _commit_chains).
+        sel_last = j == count_units - 1
+        cls_last = jnp.max(jnp.where(sel_last, cls_s, -1))
+        last_client = jnp.max(jnp.where(sel_last, idxs, -1))
+        do_promote = (count_units > 0) & (cls_last >= CLS_WEIGHT)
+        has_req_after = new_state.active & (new_state.depth > 0)
+        promoted = new_state.head_ready | \
+            (has_req_after & (new_state.head_limit <= now))
+        promoted = promoted & (
+            jnp.arange(state.capacity, dtype=jnp.int32) != last_client)
+        new_state = new_state._replace(head_ready=jnp.where(
+            do_promote, promoted, new_state.head_ready))
 
-    return _Selection(idxs=idxs, cls_s=cls_s, cost_s=costs, len_s=lens,
-                      count_units=count_units, count=count,
-                      guards_ok=guards_ok, state=new_state,
-                      last_client=last_client,
-                      cost_pc=jnp.where(sel, chain.cost_acc,
-                                        jnp.int64(0)),
-                      margin_s=margin_s)
+        return _Selection(idxs=idxs, cls_s=cls_s, cost_s=costs, len_s=lens,
+                          count_units=count_units, count=count,
+                          guards_ok=guards_ok, state=new_state,
+                          last_client=last_client,
+                          cost_pc=jnp.where(sel, chain.cost_acc,
+                                            jnp.int64(0)),
+                          margin_s=margin_s)
 
 
 # ----------------------------------------------------------------------
@@ -1335,25 +1341,27 @@ def scan_prefix_epoch(state: EngineState, now, m: int, k: int, *,
             mut = new_mut
         out = (count, guards, slot, phase, cost, lb)
         if with_metrics:
-            served = slot >= 0
-            resv = jnp.sum(served & (phase == 0))
-            met = _batch_metrics(
-                met, batch.state, count=count, resv=resv,
-                prop=count - resv, lb=jnp.sum(lb),
-                guards_ok=batch.guards_ok, rebase_fallback=trip,
-                live=good)
+            with phase_scope("telemetry"):
+                served = slot >= 0
+                resv = jnp.sum(served & (phase == 0))
+                met = _batch_metrics(
+                    met, batch.state, count=count, resv=resv,
+                    prop=count - resv, lb=jnp.sum(lb),
+                    guards_ok=batch.guards_ok, rebase_fallback=trip,
+                    live=good)
         if need_class:
-            # entry classification recomputed for telemetry only (a
-            # cheap dense pass; the decision stream is untouched)
-            tele, key_e, gate_n = _tele_entry_fold(
-                tele, st, batch.state, now, allow_limit_break,
-                batch.count, good, cost_pc=batch.cost_pc,
-                margins=batch.margins)
-            tele = _tele_flight(
-                tele, slot,
-                phase.astype(jnp.int64) + lb.astype(jnp.int64),
-                jnp.take(key_e, jnp.maximum(slot, 0)), cost, good,
-                margin=batch.margins, gate=gate_n)
+            with phase_scope("telemetry"):
+                # entry classification recomputed for telemetry only (a
+                # cheap dense pass; the decision stream is untouched)
+                tele, key_e, gate_n = _tele_entry_fold(
+                    tele, st, batch.state, now, allow_limit_break,
+                    batch.count, good, cost_pc=batch.cost_pc,
+                    margins=batch.margins)
+                tele = _tele_flight(
+                    tele, slot,
+                    phase.astype(jnp.int64) + lb.astype(jnp.int64),
+                    jnp.take(key_e, jnp.maximum(slot, 0)), cost, good,
+                    margin=batch.margins, gate=gate_n)
         carry = (mut, met, tele, dead) if narrow32 \
             else (mut, met, tele)
         return carry, out
@@ -1474,27 +1482,29 @@ def scan_chain_epoch(state: EngineState, now, m: int, k: int, *,
             mut = new_mut
         out = (count, ucount, guards, slot, cls, length)
         if with_metrics:
-            units = slot >= 0
-            # a unit's entry serve is weight-phase iff class >= 1; its
-            # induced serves are all constraint-phase
-            prop = jnp.sum(jnp.where(units, (cls >= CLS_WEIGHT)
-                                     .astype(jnp.int64), 0))
-            met = _batch_metrics(
-                met, batch.state, count=count,
-                resv=count.astype(jnp.int64) - prop, prop=prop,
-                lb=jnp.sum(units & (cls >= CLS_LB)),
-                guards_ok=batch.guards_ok, rebase_fallback=trip,
-                live=good)
+            with phase_scope("telemetry"):
+                units = slot >= 0
+                # a unit's entry serve is weight-phase iff class >= 1; its
+                # induced serves are all constraint-phase
+                prop = jnp.sum(jnp.where(units, (cls >= CLS_WEIGHT)
+                                         .astype(jnp.int64), 0))
+                met = _batch_metrics(
+                    met, batch.state, count=count,
+                    resv=count.astype(jnp.int64) - prop, prop=prop,
+                    lb=jnp.sum(units & (cls >= CLS_LB)),
+                    guards_ok=batch.guards_ok, rebase_fallback=trip,
+                    live=good)
         if need_class:
-            tele, key_e, gate_n = _tele_entry_fold(
-                tele, st, batch.state, now, allow_limit_break,
-                batch.count, good, cost_pc=batch.cost_pc,
-                margins=batch.margins)
-            tele = _tele_flight(
-                tele, slot, cls.astype(jnp.int64),
-                jnp.take(key_e, jnp.maximum(slot, 0)),
-                length.astype(jnp.int64), good,
-                margin=batch.margins, gate=gate_n)
+            with phase_scope("telemetry"):
+                tele, key_e, gate_n = _tele_entry_fold(
+                    tele, st, batch.state, now, allow_limit_break,
+                    batch.count, good, cost_pc=batch.cost_pc,
+                    margins=batch.margins)
+                tele = _tele_flight(
+                    tele, slot, cls.astype(jnp.int64),
+                    jnp.take(key_e, jnp.maximum(slot, 0)),
+                    length.astype(jnp.int64), good,
+                    margin=batch.margins, gate=gate_n)
         carry = (mut, met, tele, dead) if narrow32 \
             else (mut, met, tele)
         return carry, out
@@ -2472,12 +2482,13 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
             st = EngineState(**invariant, **mut)
         hd = ld = sd = p_new = margin_pc = None
         if need_tele:
-            # batch-entry classification, shared by the minstop
-            # telemetry delta and the flight records (ONE definition,
-            # so the two cannot drift); the bucketed ladder computes
-            # its own per-LEVEL classification internally, and XLA
-            # drops this one when nothing reads it
-            cls_e, key_e = _classify(st, now, allow_limit_break)
+            with phase_scope("telemetry"):
+                # batch-entry classification, shared by the minstop
+                # telemetry delta and the flight records (ONE definition,
+                # so the two cannot drift); the bucketed ladder computes
+                # its own per-LEVEL classification internally, and XLA
+                # drops this one when nothing reads it
+                cls_e, key_e = _classify(st, now, allow_limit_break)
         w_reslots = jnp.int64(0)
         w_hwm = jnp.int64(0)
         if bucketed:
@@ -2527,20 +2538,22 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
                        for f in _EPOCH_MUTABLE}
             margin_pc = batch.margin
             if "h" in tele or "l" in tele or "s" in tele:
-                hd, ld, sd = _telemetry_delta(
-                    batch.state, now, cls_e, key_e, batch.served,
-                    batch.served_resv, batch.lb, batch.count,
-                    "h" in tele, "l" in tele,
-                    cost_pc=batch.served_cost, with_slo="s" in tele)
+                with phase_scope("telemetry"):
+                    hd, ld, sd = _telemetry_delta(
+                        batch.state, now, cls_e, key_e, batch.served,
+                        batch.served_resv, batch.lb, batch.count,
+                        "h" in tele, "l" in tele,
+                        cost_pc=batch.served_cost, with_slo="s" in tele)
             if "p" in tele:
-                has_req = st.active & (st.depth > 0)
-                elig = cls_e != CLS_NONE
-                p_new = obsprov.prov_observe(
-                    tele["p"], now=now, elig=elig,
-                    gated=has_req & ~elig,
-                    win_cls=jnp.min(jnp.where(elig, cls_e,
-                                              CLS_NONE)),
-                    served_pc=batch.served, margins=batch.margin)
+                with phase_scope("telemetry"):
+                    has_req = st.active & (st.depth > 0)
+                    elig = cls_e != CLS_NONE
+                    p_new = obsprov.prov_observe(
+                        tele["p"], now=now, elig=elig,
+                        gated=has_req & ~elig,
+                        win_cls=jnp.min(jnp.where(elig, cls_e,
+                                                  CLS_NONE)),
+                        served_pc=batch.served, margins=batch.margin)
         trip = jnp.bool_(False)
         good = jnp.bool_(True)
         if narrow32:
@@ -2558,37 +2571,39 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
             mut = new_mut
         out = (count, resv_count, progress, lvl_count)
         if with_metrics:
-            met = _batch_metrics(
-                met, batch_state, count=count,
-                resv=resv_count,
-                prop=count - resv_count,
-                lb=lb_total,
-                # a calendar batch with candidates that cannot make
-                # progress is the guard-trip analog (serial fallback)
-                guards_ok=progress | ~good, rebase_fallback=trip,
-                live=good,
-                ladder_levels_used=levels_used,
-                ladder_base_decisions=base_decs,
-                ladder_fallbacks=ladder_fb,
-                wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots)
+            with phase_scope("telemetry"):
+                met = _batch_metrics(
+                    met, batch_state, count=count,
+                    resv=resv_count,
+                    prop=count - resv_count,
+                    lb=lb_total,
+                    # a calendar batch with candidates that cannot make
+                    # progress is the guard-trip analog (serial fallback)
+                    guards_ok=progress | ~good, rebase_fallback=trip,
+                    live=good,
+                    ladder_levels_used=levels_used,
+                    ladder_base_decisions=base_decs,
+                    ladder_fallbacks=ladder_fb,
+                    wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots)
         if need_tele:
-            tele = _tele_fold(tele, hd, ld, good, sd)
-            if "p" in tele:
-                tele["p"] = obsprov.prov_select(good, p_new,
-                                                tele["p"])
-            if "f" in tele:
-                # per-client-per-batch records (the calendar engine
-                # emits counts, not a stream); GATED served, so a
-                # dead batch records nothing
-                has_req = st.active & (st.depth > 0)
-                gate_n = jnp.sum(has_req & (cls_e == CLS_NONE)) \
-                    .astype(jnp.int64)
-                iota = jnp.arange(st.capacity, dtype=jnp.int32)
-                tele = _tele_flight(
-                    tele, jnp.where(served > 0, iota, -1),
-                    cls_e.astype(jnp.int64), key_e,
-                    served.astype(jnp.int64), good,
-                    margin=margin_pc, gate=gate_n)
+            with phase_scope("telemetry"):
+                tele = _tele_fold(tele, hd, ld, good, sd)
+                if "p" in tele:
+                    tele["p"] = obsprov.prov_select(good, p_new,
+                                                    tele["p"])
+                if "f" in tele:
+                    # per-client-per-batch records (the calendar engine
+                    # emits counts, not a stream); GATED served, so a
+                    # dead batch records nothing
+                    has_req = st.active & (st.depth > 0)
+                    gate_n = jnp.sum(has_req & (cls_e == CLS_NONE)) \
+                        .astype(jnp.int64)
+                    iota = jnp.arange(st.capacity, dtype=jnp.int32)
+                    tele = _tele_flight(
+                        tele, jnp.where(served > 0, iota, -1),
+                        cls_e.astype(jnp.int64), key_e,
+                        served.astype(jnp.int64), good,
+                        margin=margin_pc, gate=gate_n)
         carry = (mut, acc + served, met, tele, dead) if narrow32 \
             else (mut, acc + served, met, tele)
         return carry, out

@@ -144,7 +144,7 @@ def wheel_scan_pallas(keys, slot, nb: int, *,
         functools.partial(_wheel_kernel, nb=nb, rows=rows),
         out_shape=[out1, out1, out1,
                    jax.ShapeDtypeStruct((8, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="wheel_scan",
     )(slot.reshape(shape).astype(jnp.int32), khi.reshape(shape),
       klo.reshape(shape))
     cnt = cnt[:, 0]

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.compile_plane import phase_scope
 from . import kernels
 from . import fastpath
 from .state import EngineState
@@ -99,15 +100,17 @@ def clamped_ingest(state: EngineState, counts, t_base, *, waves: int,
     that both ingest exactly what the round loop's host clamp would
     have, so the clamp must not be able to drift between them."""
     n = state.capacity
-    cost1 = jnp.ones((n,), dtype=jnp.int64)
-    headroom = jnp.minimum(
-        jnp.int32(state.ring_capacity) - state.depth,
-        jnp.int32(waves))
-    c = jnp.minimum(counts, headroom)
-    wave_times = jnp.asarray(t_base, jnp.int64) + jnp.arange(
-        waves, dtype=jnp.int64) * dt_wave
-    return kernels.ingest_superwave(
-        state, c, wave_times, cost1, cost1, cost1, anticipation_ns=0)
+    with phase_scope("ingest"):
+        cost1 = jnp.ones((n,), dtype=jnp.int64)
+        headroom = jnp.minimum(
+            jnp.int32(state.ring_capacity) - state.depth,
+            jnp.int32(waves))
+        c = jnp.minimum(counts, headroom)
+        wave_times = jnp.asarray(t_base, jnp.int64) + jnp.arange(
+            waves, dtype=jnp.int64) * dt_wave
+        return kernels.ingest_superwave(
+            state, c, wave_times, cost1, cost1, cost1,
+            anticipation_ns=0)
 
 
 def make_epoch_step(*, engine: str, m: int, kw: dict, dt_epoch_ns: int,

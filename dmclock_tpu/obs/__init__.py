@@ -48,7 +48,7 @@ See ``docs/OBSERVABILITY.md`` for metric names and schemas.
 
 from .registry import (Counter, Gauge, Histogram, MetricsHTTPServer,
                        MetricsRegistry, TimerMetric, default_registry,
-                       publish_span_gauges, start_http_server)
+                       start_http_server)
 from .trace import DecisionTrace, validate_trace_file
 from .spans import SpanTracer
 from .trace_export import export_chrome_trace, validate_chrome_trace
@@ -64,7 +64,6 @@ from . import alerts, capacity, compile_plane, device, flight, \
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "TimerMetric",
     "default_registry", "MetricsHTTPServer", "start_http_server",
-    "publish_span_gauges",
     "DecisionTrace", "validate_trace_file",
     "SpanTracer", "export_chrome_trace", "validate_chrome_trace",
     "Watchdog", "SloPlane", "SloEvaluator", "mount_slo_api",

@@ -44,6 +44,7 @@ must never kill the launch it observes.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time as _walltime
 import weakref
@@ -75,6 +76,71 @@ def live_executables(cache: str) -> list:
     a Pallas kernel is in it: ``tpu_custom_call``)."""
     return [c for w in list(_ALL_WRAPPERS) if w._cache == cache
             for c in list(w._compiled.values()) if c is not _DISPATCH]
+
+
+# The fixed device-phase set of an epoch program (docs/OBSERVABILITY.md
+# "Device phases").  Each phase is a ``jax.named_scope`` named
+# ``dmc.<phase>``: trace-time metadata (``op_name``) that changes no
+# op -- the compiled program is the unscoped one's up to metadata and
+# the numbering of instruction names.
+PHASES = ("ingest", "window", "select", "commit", "telemetry")
+_PHASE_PREFIX = "dmc."
+_OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?(%[^\s=]+) = .*?op_name="([^"]*)"')
+# Compiled -> {instruction: phase}, built on first read
+_PHASE_MAPS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def phase_scope(phase: str):
+    """``with phase_scope("select"):`` -- the named scope of one of
+    :data:`PHASES`."""
+    # a real raise, not an assert: under PYTHONOPTIMIZE a typo'd phase
+    # would silently fall out of every phase's attribution
+    if phase not in PHASES:
+        raise ValueError(f"unknown device phase {phase!r} "
+                         f"(phases: {PHASES})")
+    return jax.named_scope(_PHASE_PREFIX + phase)
+
+
+def _phase_of(op_name: str) -> Optional[str]:
+    for part in op_name.split("/"):
+        if part.startswith(_PHASE_PREFIX) and \
+                part[len(_PHASE_PREFIX):] in PHASES:
+            return part[len(_PHASE_PREFIX):]
+    return None
+
+
+def phase_map(compiled) -> Dict[str, str]:
+    """Instruction name -> phase of one compiled executable (see
+    :func:`op_phases`)."""
+    out = {}
+    for line in compiled.as_text().splitlines():
+        m = _OP_NAME.match(line)
+        if m:
+            ph = _phase_of(m.group(2))
+            if ph is not None:
+                out[m.group(1)] = ph
+    return out
+
+
+def op_phases(cache: str = "stream.chunk") -> Dict[str, str]:
+    """Instruction name (``%sort.46``, ``%fusion.433``) -> device phase,
+    for every instruction of the executables ``live_executables(cache)``
+    holds whose ``op_name`` carries a phase scope (a fusion carries its
+    root's).  The first ``dmc.<phase>`` component of the name decides;
+    unscoped instructions are left out.  Names are unique within one
+    executable only: with several, a later one's entry wins.  Parsed
+    from ``Compiled.as_text()`` on first read and cached per
+    executable -- a reading path, never a launch path.  ``{}`` with the
+    plane disabled (it then holds no executables)."""
+    if not _PLANE.enabled:
+        return {}
+    out: Dict[str, str] = {}
+    for c in live_executables(cache):
+        m = _PHASE_MAPS.get(c)
+        if m is None:
+            m = _PHASE_MAPS[c] = phase_map(c)
+        out.update(m)
+    return out
 
 
 # one retrace event ring entry per (re)trace, what the watchdog's

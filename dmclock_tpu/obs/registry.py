@@ -295,38 +295,6 @@ class MetricsRegistry:
         return json.dumps(self.snapshot(), **json_kw)
 
 
-def publish_span_gauges(registry: MetricsRegistry, summary: dict,
-                        labels: Optional[Dict[str, str]] = None
-                        ) -> None:
-    """Expose span-derived dispatch-tax gauges from a bench/sim span
-    summary (the dict ``bench.py --spans`` computes from
-    ``obs.spans.SpanTracer`` category deltas over its timed chains) so
-    the Prometheus endpoint serves them alongside the histogram
-    families:
-
-    - ``dmclock_dispatch_ms_per_launch`` -- host dispatch self-time
-      per device launch (PROFILE.md findings 17-18);
-    - ``dmclock_device_ms_per_launch`` -- device-side time per launch;
-    - ``dmclock_host_overhead_frac`` -- host-side (non-device) share
-      of the measured wall time.
-    """
-    rows = (
-        ("dmclock_dispatch_ms_per_launch", "dispatch_ms_per_launch",
-         "host dispatch self-time per device launch over the timed "
-         "region (span tracer; docs/OBSERVABILITY.md tracing plane)"),
-        ("dmclock_device_ms_per_launch", "device_ms_per_launch",
-         "device-side time per launch over the timed region (span "
-         "tracer)"),
-        ("dmclock_host_overhead_frac", "host_overhead_frac",
-         "host-side (dispatch + prep + fetch + drain) share of the "
-         "measured wall time (span tracer)"),
-    )
-    for name, key, help_text in rows:
-        if key in summary:
-            registry.gauge(name, help_text,
-                           labels=labels).set(float(summary[key]))
-
-
 _DEFAULT = MetricsRegistry()
 
 

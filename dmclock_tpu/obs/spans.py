@@ -22,7 +22,10 @@ structured span tracer for **host-side** events:
   rotation checkpoints so the span stream survives a SIGKILL restart.
 
 **Spans are host-side only, never in-graph**: a tracer observes wall
-time around device launches; it cannot perturb a decision.  The
+time around device launches; it cannot perturb a decision.  Each span
+is also a ``jax.profiler.TraceAnnotation``, so while a profiler runs
+the program's spans sit on the device trace's timeline beside the ops
+they wait for; the rows here keep their own clock.  The
 tracing-off path is a single ``None`` check per call site
 (:func:`span` returns a shared no-op context manager), gated in CI to
 bit-identical decisions and ~zero overhead.  See
@@ -37,6 +40,8 @@ import threading
 import time as _walltime
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # The fixed category set (docs/OBSERVABILITY.md).  Every span
 # carries exactly one; the exporter validates against this set so a
@@ -92,7 +97,7 @@ class _Span:
     it is a few hundred ns."""
 
     __slots__ = ("_tr", "name", "cat", "args", "t0", "child_ns",
-                 "depth")
+                 "depth", "ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tr = tracer
@@ -102,6 +107,7 @@ class _Span:
         self.t0 = 0
         self.child_ns = 0
         self.depth = 0
+        self.ann = None
 
     def __enter__(self):
         self._tr._push(self)
@@ -177,6 +183,8 @@ class SpanTracer:
         # and a span visible with t0 still 0 would read as infinitely
         # old -- defeating the watchdog's in-flight stall suppression
         # it exists to serve
+        sp.ann = TraceAnnotation(sp.name)
+        sp.ann.__enter__()
         sp.t0 = self._clock()
         st.append(sp)
 
@@ -196,9 +204,10 @@ class SpanTracer:
         # each leaked child -- their rows are lost, not silent
         leaked = 0
         while st[-1] is not sp:
-            st.pop()
+            st.pop().ann.__exit__(None, None, None)
             leaked += 1
         st.pop()
+        sp.ann.__exit__(None, None, None)
         if leaked:
             with self._mtx:
                 self.spans_leaked += leaked

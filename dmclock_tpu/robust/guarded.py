@@ -440,8 +440,9 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
         if on_retry is not None:
             on_retry(attempt, exc)
 
-    counts_dev = None if counts is None \
-        else jnp.asarray(counts, dtype=jnp.int32)
+    with _spans.span(tracer, "stream.upload", "host_prep"):
+        counts_dev = None if counts is None \
+            else jnp.asarray(counts, dtype=jnp.int32)
 
     def one():
         with _spans.span(tracer, "stream.dispatch", "dispatch",
@@ -458,19 +459,21 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
                              sleep=sleep, on_retry=count_retry)
 
     guard_field = stream_mod.STREAM_GUARD_FIELD[engine]
-    guards = np.asarray(jax.device_get(out.outs[guard_field]))
-    if bool(guards.all()):
-        fetched = jax.device_get(out.outs)
-        views = tuple(stream_mod.epoch_view(engine, fetched, i)
-                      for i in range(epochs))
-        return StreamGuarded(
-            state=out.state, epochs=tuple((v,) for v in views),
-            counts=tuple(stream_mod.epoch_decisions(engine, fetched, i)
-                         for i in range(epochs)),
-            guard_trips=(0,) * epochs, stream_fallback=0,
-            retries=retry_count[0], hists=out.hists,
-            ledger=out.ledger, flight=out.flight, slo=out.slo,
-            prov=out.prov)
+    with _spans.span(tracer, "stream.fetch", "fetch", epochs=epochs):
+        guards = np.asarray(jax.device_get(out.outs[guard_field]))
+        if bool(guards.all()):
+            fetched = jax.device_get(out.outs)
+            views = tuple(stream_mod.epoch_view(engine, fetched, i)
+                          for i in range(epochs))
+            decided = tuple(stream_mod.epoch_decisions(engine, fetched,
+                                                       i)
+                            for i in range(epochs))
+            return StreamGuarded(
+                state=out.state, epochs=tuple((v,) for v in views),
+                counts=decided, guard_trips=(0,) * epochs,
+                stream_fallback=0, retries=retry_count[0], hists=out.hists,
+                ledger=out.ledger, flight=out.flight, slo=out.slo,
+                prov=out.prov)
 
     # a guard tripped somewhere in the chunk: the fused program cannot
     # run the tag32/serial resumes mid-scan, so the whole chunk is

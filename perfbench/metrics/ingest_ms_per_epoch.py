@@ -1,0 +1,10 @@
+"""ingest_ms_per_epoch: device time of the dmc.ingest phase, the
+superwave ingest (admission clamp and ring append), per traced epoch
+(phase_ms.py)."""
+
+from perfbench.harness import discover
+
+
+def read(run):
+    return discover._module(run.cell.root, "metrics",
+                            "phase_ms").ms_per_epoch(run, "ingest")

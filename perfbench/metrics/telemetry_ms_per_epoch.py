@@ -1,0 +1,10 @@
+"""telemetry_ms_per_epoch: device time of the dmc.telemetry phase, the
+device metrics vector and telemetry folds, per traced epoch
+(phase_ms.py)."""
+
+from perfbench.harness import discover
+
+
+def read(run):
+    return discover._module(run.cell.root, "metrics",
+                            "phase_ms").ms_per_epoch(run, "telemetry")

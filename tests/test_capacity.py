@@ -490,7 +490,6 @@ class TestDocDrift:
         from dmclock_tpu.obs import provenance as obsprov
         from dmclock_tpu.obs import slo as obsslo
         from dmclock_tpu.obs.alerts import SloEvaluator
-        from dmclock_tpu.obs.registry import publish_span_gauges
 
         reg = MetricsRegistry()
         obsprov.publish_provenance(reg, obsprov.prov_init(2))
@@ -508,9 +507,6 @@ class TestDocDrift:
                                              dtype=np.int64))
         obsslo.publish_shard_windows(
             reg, np.zeros((2, 2, obsslo.W_FIELDS), dtype=np.int64))
-        publish_span_gauges(reg, {"dispatch_ms_per_launch": 1.0,
-                                  "device_ms_per_launch": 1.0,
-                                  "host_overhead_frac": 0.1})
         Watchdog(obsspans.SpanTracer(), registry=reg,
                  log=lambda _l: None)
         SloEvaluator(obsslo.SloPlane(2, dt_epoch_ns=10 ** 8),

@@ -15,7 +15,7 @@ import pytest
 
 from dmclock_tpu.obs import spans as S
 from dmclock_tpu.obs import trace_export as TE
-from dmclock_tpu.obs.registry import MetricsRegistry, publish_span_gauges
+from dmclock_tpu.obs.registry import MetricsRegistry
 from dmclock_tpu.obs.watchdog import Watchdog
 
 REPO = Path(__file__).resolve().parent.parent
@@ -156,6 +156,55 @@ class TestSpanTracer:
         sp.__exit__(None, None, None)
         assert len(tr.rows()) == 1
         assert tr.spans_leaked == 1
+
+    def test_every_pop_closes_its_annotation(self, monkeypatch):
+        """Each span is a profiler TraceAnnotation from push to pop,
+        and a popped-through (leaked) child closes its own too."""
+        events = []
+
+        class Ann:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name))
+
+        monkeypatch.setattr(S, "TraceAnnotation", Ann)
+        tr = S.SpanTracer()
+        with tr.span("a", "host_prep"):
+            pass
+        outer = tr.span("outer", "host_prep")
+        outer.__enter__()
+        tr.span("leaked", "dispatch").__enter__()
+        outer.__exit__(None, None, None)
+        assert events == [("enter", "a"), ("exit", "a"),
+                          ("enter", "outer"), ("enter", "leaked"),
+                          ("exit", "leaked"), ("exit", "outer")]
+
+    def test_spans_land_on_the_profiler_trace(self, tmp_path):
+        import glob
+
+        import jax
+        import jax.numpy as jnp
+
+        tr = S.SpanTracer()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("test.on_profiler_clock", "host_prep"):
+                jnp.arange(8).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = jax.profiler.ProfileData.from_file(path)
+        names = {ev.name for plane in pd.planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for ev in line.events}
+        assert "test.on_profiler_clock" in names
+        assert tr.rows()[-1]["name"] == "test.on_profiler_clock"
 
 
 class TestChromeExport:
@@ -523,26 +572,6 @@ class TestWatchdog:
         assert wd.poll_errors == 0
 
 
-class TestSpanGauges:
-    def test_publish_span_gauges(self):
-        reg = MetricsRegistry()
-        publish_span_gauges(reg, {"dispatch_ms_per_launch": 17.25,
-                                  "device_ms_per_launch": 3.5,
-                                  "host_overhead_frac": 0.81},
-                            labels={"workload": "cfg4"})
-        text = reg.prometheus()
-        assert 'dmclock_dispatch_ms_per_launch{workload="cfg4"} ' \
-               '17.25' in text
-        assert 'dmclock_host_overhead_frac{workload="cfg4"} 0.81' \
-            in text
-
-    def test_partial_summary_publishes_partial(self):
-        reg = MetricsRegistry()
-        publish_span_gauges(reg, {"dispatch_ms_per_launch": 1.0})
-        names = {m.name for m in reg.metrics()}
-        assert names == {"dmclock_dispatch_ms_per_launch"}
-
-
 class TestQueueTracing:
     """Spans through the TPU pull queue: decisions bit-identical with
     tracing on/off, and the decomposition categories all appear."""
@@ -616,6 +645,25 @@ class TestGuardedTracing:
         # batches ride inside the single launch)
         assert counts.get("dispatch", 0) >= 1
         assert counts.get("device_compute", 0) >= 1
+
+    def test_stream_chunk_spans_in_order(self):
+        """One guarded stream chunk: the counts' upload, the launch,
+        the wait and the readback, each in its own category."""
+        from __graft_entry__ import _preloaded_state
+        from dmclock_tpu.robust.guarded import run_stream_chunk_guarded
+
+        tr = S.SpanTracer()
+        res = run_stream_chunk_guarded(
+            _preloaded_state(64, 4, ring=16), 0,
+            np.ones((2, 64), np.int32), engine="prefix", epochs=2, m=2,
+            k=8, dt_epoch_ns=10 ** 8, waves=2, tracer=tr)
+        assert res.stream_fallback == 0
+        rows = [(r["name"], r["cat"]) for r in tr.rows()
+                if r["name"].startswith("stream.")]
+        assert rows == [("stream.upload", "host_prep"),
+                        ("stream.dispatch", "dispatch"),
+                        ("stream.device_wait", "device_compute"),
+                        ("stream.fetch", "fetch")]
 
 
 class TestSupervisorSpanLog:

@@ -125,3 +125,27 @@ def test_mesh_int64_max_reduce_compiles(topo):
         check_vma=False)
     compiled = jax.jit(fn).lower(vec).compile()
     assert "all-reduce" in compiled.as_text()
+
+
+def test_prefix_chunk_phases_on_v5e(topo, one_chip):
+    """The benchmark cell's prefix chunk at 100k clients (ring 128,
+    k=4096, 16 waves, metrics on; m=4 keeps the compile short, and
+    the scopes do not depend on m): every device phase names
+    instructions, and the ring-rotate kernels are the window's."""
+    from dmclock_tpu.obs import compile_plane as CP
+
+    st = _shapes(jax.eval_shape(lambda: init_state(N, 128)), one_chip)
+    counts = jax.ShapeDtypeStruct((4, N), jnp.int32, sharding=one_chip)
+    epoch0 = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    fn = SM.build_stream_chunk(
+        engine="prefix", epochs=4, m=4, k=4096, dt_epoch_ns=10 ** 8,
+        waves=16, allow_limit_break=True, with_metrics=True)
+    with jax.default_device(topo.devices[0]):
+        compiled = jax.jit(fn).lower(st, epoch0, counts).compile()
+    phases = CP.phase_map(compiled)
+    assert set(phases.values()) == set(CP.PHASES)
+    kernels = [line.split(" = ", 1)[0].split()[-1]
+               for line in compiled.as_text().splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert kernels
+    assert {phases.get(k) for k in kernels} == {"window"}
