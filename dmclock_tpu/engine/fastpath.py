@@ -222,6 +222,49 @@ def _rotate_rows_xla(ring, q0, wsize: int):
     return r[:, :wsize].T
 
 
+def rotate_on_pallas(q: int) -> bool:
+    """Whether ``ring_window`` rotates a ring of width ``q`` with the
+    Pallas kernel: on TPU, given a full lane tile (2q >= 128 int32
+    lanes)."""
+    return on_tpu() and q >= 64
+
+
+# Cost of one ring-window prefetch (rotate, shift tiling, layout
+# copies) over one window-row select in each batch, in rows per ring
+# slot; the XLA figure is per roll step too (log2(Q) masked passes).
+# Both costs scale with N, so the ratio does not.  Fitted on a TPU v5e
+# at N=100k: Pallas from the prefix stream chunk's device time at
+# widths 5-25 (R 5.39 ms, a 9.72 us at Q=128); XLA from a timed
+# prefetch at Q=32 (0.68 ms), the widest ring the chip rolls with XLA.
+_PREFETCH_ROWS_PER_SLOT_PALLAS = 4.3
+_PREFETCH_ROWS_PER_SLOT_STEP_XLA = 0.44
+
+
+def prefix_window_width(m: int, q: int, *, pallas: bool | None = None,
+                        window_m: int | None = None) -> int:
+    """Batches per ring-window prefetch in a prefix epoch of ``m``
+    batches over rings of width ``q``.
+
+    A window of w rows costs one prefetch R and, in each of its w
+    batches, w - 1 one-hot row selects of cost a (``_window_rows``), so
+    a batch costs a*w + R/w up to a constant.  The width is the divisor
+    of m, at most min(m, q), that minimises it: about sqrt(R/a), and m
+    itself when m is below that.  ``pallas`` is the rotate backend
+    (None: what ``ring_window`` picks here).  An explicit ``window_m``
+    overrides the choice (capped at m)."""
+    if window_m is not None:
+        return min(int(window_m), m)
+    if pallas is None:
+        pallas = rotate_on_pallas(q)
+    if pallas:
+        rows = _PREFETCH_ROWS_PER_SLOT_PALLAS * q
+    else:
+        rows = _PREFETCH_ROWS_PER_SLOT_STEP_XLA * q * \
+            max(1, (q - 1).bit_length())
+    widths = [w for w in range(1, min(m, q) + 1) if m % w == 0]
+    return min(widths, key=lambda w: w + rows / w)
+
+
 def ring_window(state: EngineState, m: int,
                 use_pallas: bool | None = None) -> RingWindow:
     """Prefetch the next ``min(m, Q)`` ring elements of every client,
@@ -242,9 +285,8 @@ def ring_window(state: EngineState, m: int,
     q0 = state.q_head
     wsize = min(m, q)
 
-    # the Pallas path needs a full lane tile (2q >= 128 int32 lanes)
     if use_pallas is None:
-        use_pallas = on_tpu() and q >= 64
+        use_pallas = rotate_on_pallas(q)
     with phase_scope("window"):
         if use_pallas:
             n = q0.shape[0]
@@ -1274,10 +1316,12 @@ def scan_prefix_epoch(state: EngineState, now, m: int, k: int, *,
 
     ``window_m`` (STATIC) chunks the ring-window prefetch: the epoch
     runs ``m / window_m`` prefetch chunks of ``window_m`` batches
-    each, so wide epochs (m=64) amortize per-epoch dispatch without
-    growing the unrolled window-select chain past ``window_m`` rows
-    (the chain's cost scales with the window width -- PROFILE.md).
-    Must divide m; None = one m-row window (the original layout).
+    each, so wide epochs amortize per-epoch dispatch without growing
+    the unrolled window-select chain past ``window_m`` rows (each
+    batch pays one select per window row).  Must divide m; None = the
+    width ``prefix_window_width`` derives from m, the ring width and
+    the rotate backend.  Decisions, state and telemetry are
+    bit-identical at every width (tests/test_radix.py).
 
     ``hists`` / ``ledger`` / ``flight`` / ``slo`` / ``prov`` (each
     None = off; presence is the static flag) are INITIAL telemetry
@@ -1292,7 +1336,7 @@ def scan_prefix_epoch(state: EngineState, now, m: int, k: int, *,
     tests/test_slo.py, tests/test_provenance.py).
     """
     assert tag_width in (32, 64), tag_width
-    w = m if window_m is None else min(int(window_m), m)
+    w = prefix_window_width(m, state.ring_capacity, window_m=window_m)
     assert w > 0 and m % w == 0, "window_m must divide m"
     narrow32 = tag_width == 32
     invariant = {f: getattr(state, f) for f in _EPOCH_INVARIANT}

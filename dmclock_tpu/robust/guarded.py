@@ -174,6 +174,20 @@ def _guard_vec(engine: str, result):
     return np.asarray(ok)
 
 
+def _window_args(engine: str, m: int, state, window_m) -> dict:
+    """Dispatch-span args of a prefix epoch: the ring-window width the
+    scan prefetches (``fastpath.prefix_window_width``) and the
+    prefetches per epoch.  Empty for the other engines, which prefetch
+    per batch."""
+    if engine != "prefix":
+        return {}
+    from ..engine import fastpath
+
+    w = fastpath.prefix_window_width(m, state.ring_capacity,
+                                     window_m=window_m)
+    return {"window_m": w, "prefetches": m // w}
+
+
 def run_epoch_guarded(state, now, *, engine: str = "prefix",
                       m: int, k: int = 0, chain_depth: int = 4,
                       anticipation_ns: int = 0,
@@ -266,8 +280,10 @@ def run_epoch_guarded(state, now, *, engine: str = "prefix",
         def one():
             # dispatch (the async jit call) and the device wait are
             # separate spans: their ratio is the dispatch tax
+            args = {} if tracer is None \
+                else _window_args(engine, m_run, st, window_m)
             with _spans.span(tracer, "guarded.dispatch", "dispatch",
-                             engine=engine, m=m_run):
+                             engine=engine, m=m_run, **args):
                 out = call()
             with _spans.span(tracer, "guarded.device_wait",
                              "device_compute"):
@@ -443,10 +459,12 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
     with _spans.span(tracer, "stream.upload", "host_prep"):
         counts_dev = None if counts is None \
             else jnp.asarray(counts, dtype=jnp.int32)
+    dispatch_args = {} if tracer is None \
+        else _window_args(engine, m, state, window_m)
 
     def one():
         with _spans.span(tracer, "stream.dispatch", "dispatch",
-                         engine=engine, epochs=epochs):
+                         engine=engine, epochs=epochs, **dispatch_args):
             out = fn(state, jnp.int64(epoch0), counts_dev,
                      hists, ledger, flight, slo, prov)
         if overlap is not None:

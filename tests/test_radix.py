@@ -30,7 +30,8 @@ import pytest
 from dmclock_tpu.core import ClientInfo
 from dmclock_tpu.core.timebase import MAX_TAG, MIN_TAG, NS_PER_SEC
 from dmclock_tpu.engine import kernels
-from dmclock_tpu.engine.fastpath import (scan_calendar_epoch,
+from dmclock_tpu.engine.fastpath import (prefix_window_width,
+                                         scan_calendar_epoch,
                                          scan_chain_epoch,
                                          scan_prefix_epoch,
                                          speculate_chain_batch,
@@ -418,20 +419,76 @@ def test_tag32_initial_misfit_returns_input_state():
 # window_m chunked prefetch
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("window_m", [1, 2, 4])
+@pytest.mark.parametrize("window_m", [1, 2, 4, None])
 def test_window_m_chunking_is_invisible(window_m):
-    """m=64-style wide epochs chunk the ring prefetch; the decision
-    stream and final state must not depend on the chunking."""
+    """Wide epochs chunk the ring prefetch; the decision stream, the
+    final state and every telemetry accumulator must equal one m-row
+    window's.  The derived default (None) runs on a ring narrow enough
+    that ``prefix_window_width`` picks fewer rows than m, so the
+    chunked path is the one under test."""
+    from dmclock_tpu.obs import flight as obsflight
+    from dmclock_tpu.obs import histograms as obshist
+
+    m, ring = 16, 16
+    assert prefix_window_width(m, ring, window_m=window_m) < m
     infos = {c: ClientInfo(0, 1 + (c % 3), 0) for c in range(10)}
-    state = deep_state(infos, depth=8)
+    state = build_state(infos, [(c, S, 1, 1, 1) for _ in range(8)
+                                for c in infos], ring=ring)
     now = jnp.int64(20 * S)
-    ref = scan_prefix_epoch(state, now, 4, 8, anticipation_ns=0)
-    ch = scan_prefix_epoch(state, now, 4, 8, anticipation_ns=0,
-                           window_m=window_m)
-    for f in ("count", "guards_ok", "slot", "phase", "cost", "lb"):
-        assert np.array_equal(jax.device_get(getattr(ref, f)),
-                              jax.device_get(getattr(ch, f))), f
+
+    def run(wm):
+        return scan_prefix_epoch(
+            state, now, m, 4, anticipation_ns=0, window_m=wm,
+            with_metrics=True, hists=obshist.hist_zero(),
+            ledger=obshist.ledger_zero(state.capacity),
+            flight=obsflight.flight_init(64))
+
+    ref, ch = run(m), run(window_m)
+    assert (jax.device_get(ref.count) > 0).all()   # every batch serves
+    for f in ("count", "guards_ok", "slot", "phase", "cost", "lb",
+              "metrics", "hists", "ledger", "flight"):
+        a, b = jax.device_get((getattr(ref, f), getattr(ch, f)))
+        assert jax.tree_util.tree_all(jax.tree_util.tree_map(
+            np.array_equal, a, b)), f
     assert_states_equal(ref.state, ch.state)
+
+
+# (m, q, rotate on Pallas) shapes the width rule is held to
+_WIDTH_SHAPES = [(m, q, pallas) for m in (1, 6, 64, 98, 100, 200)
+                 for q, pallas in ((16, False), (64, False), (64, True),
+                                   (128, True))]
+
+
+def test_prefix_window_width_cell_shape():
+    """The benchmark cell's shape (m=100 batches, Q=128, Pallas rotate)
+    gets the width calibrated on the chip."""
+    assert prefix_window_width(100, 128, pallas=True) == 25
+
+
+@pytest.mark.parametrize("m,q,pallas", _WIDTH_SHAPES)
+def test_prefix_window_width_divides_m_within_ring(m, q, pallas):
+    w = prefix_window_width(m, q, pallas=pallas)
+    assert m % w == 0 and 1 <= w <= min(m, q)
+
+
+@pytest.mark.parametrize("m", [29, 61, 127])
+def test_prefix_window_width_prime_m_keeps_one_window(m):
+    """A prime m above the optimum has only 1 and m to choose from: one
+    prefetch per batch costs more than m - 1 selects, so it keeps m."""
+    assert m > prefix_window_width(100, 128, pallas=True)
+    assert prefix_window_width(m, 128, pallas=True) == m
+
+
+@pytest.mark.parametrize("m", [1, 2, 5, 16, 20])
+def test_prefix_window_width_small_m_is_one_window(m):
+    """At or below the optimum the epoch keeps one m-row window: the
+    program of small epochs is unchanged."""
+    assert prefix_window_width(m, 128, pallas=True) == m
+
+
+def test_prefix_window_width_explicit_window_m_overrides():
+    assert prefix_window_width(100, 128, pallas=True, window_m=4) == 4
+    assert prefix_window_width(100, 128, pallas=True, window_m=500) == 100
 
 
 def test_window_m_must_divide_m():

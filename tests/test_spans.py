@@ -665,6 +665,36 @@ class TestGuardedTracing:
                         ("stream.device_wait", "device_compute"),
                         ("stream.fetch", "fetch")]
 
+    def test_prefix_dispatch_spans_carry_window_width(self):
+        """The prefix engine's dispatch spans, stream and round loop,
+        carry the ring-window width the scan prefetches and the
+        prefetches per epoch; other engines' spans carry neither."""
+        from __graft_entry__ import _preloaded_state
+        from dmclock_tpu.engine.fastpath import prefix_window_width
+        from dmclock_tpu.robust.guarded import (run_epoch_guarded,
+                                                run_stream_chunk_guarded)
+
+        m, ring = 16, 16
+        w = prefix_window_width(m, ring)
+        assert w < m
+        tr = S.SpanTracer()
+        st = _preloaded_state(64, 4, ring=ring)
+        run_stream_chunk_guarded(
+            st, 0, np.ones((1, 64), np.int32), engine="prefix", epochs=1,
+            m=m, k=8, dt_epoch_ns=10 ** 8, waves=2, tracer=tr)
+        run_epoch_guarded(st, 10 ** 9, engine="prefix", m=m, k=8,
+                          tracer=tr)
+        run_epoch_guarded(st, 10 ** 9, engine="calendar", m=2, k=4,
+                          tracer=tr)
+        args = {(r["name"], (r["args"] or {}).get("engine")): r["args"]
+                for r in tr.rows() if r["cat"] == "dispatch"}
+        want = {"window_m": w, "prefetches": m // w}
+        assert {k: args["stream.dispatch", "prefix"][k]
+                for k in want} == want
+        assert {k: args["guarded.dispatch", "prefix"][k]
+                for k in want} == want
+        assert "window_m" not in args["guarded.dispatch", "calendar"]
+
 
 class TestSupervisorSpanLog:
     def _job(self, span_log=None):
