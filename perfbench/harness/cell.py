@@ -99,9 +99,10 @@ def _check(cell, con, pre, arrivals, loop, allow: bool) -> dict:
 
     cfg, tr = cell.config, cell.traffic
     ref = R.initial(con, pre, int(cfg["ring"]))
+    cost = jnp.asarray(con["cost"], jnp.int64)
     kw = dict(waves=int(cfg["engine"]["waves"]),
-              epoch_ns=int(tr["epoch_ns"]), cost=int(cfg["cost"]),
-              rho=int(cfg["rho"]), delta=int(cfg["delta"]), allow=allow)
+              epoch_ns=int(tr["epoch_ns"]), rho=int(cfg["rho"]),
+              delta=int(cfg["delta"]), allow=allow)
     eng = cfg["engine"]
     cap = int(eng["m"]) * int(eng["k"])
     capacity = float(cfg["capacity_ops_per_s_per_client"]) * \
@@ -111,7 +112,7 @@ def _check(cell, con, pre, arrivals, loop, allow: bool) -> dict:
         ordered = rec.slots is not None
         ref, v, keys = R.epoch(ref, jnp.asarray(raw),
                                jnp.asarray(rec.served), e * kw["epoch_ns"],
-                               keep_keys=ordered, **kw)
+                               cost, keep_keys=ordered, **kw)
         verdicts.append(v)
         if ordered:
             t = rec.slots.size

@@ -1,10 +1,10 @@
 """One general traffic and population generator, driven by data.
 
-A configuration file gives the population (contracts, preload); a
-traffic file gives the arrival process.  Everything is drawn from
-``--seed`` with numpy's PCG64 keyed by ``(seed, stream, index)``, so a
-run regenerates any epoch's arrivals without storing them, and the
-same seed always gives the same inputs.  Different seeds give the same
+A configuration file gives the population (client groups with their
+contracts and request cost, preload); a traffic file gives the arrival
+process.  Everything is drawn from ``--seed`` with numpy's PCG64 keyed
+by ``(seed, stream, index)``, so a run regenerates any epoch's arrivals
+without storing them, and the same seed always gives the same inputs.  Different seeds give the same
 sizes and rates in another order: the Zipf weights are one fixed set
 whose assignment to clients is shuffled by the seed.
 
@@ -48,28 +48,57 @@ def zipf_weights(n: int, s: float, lo: float, hi: float) -> np.ndarray:
     return np.clip(w / w[n // 2], lo, hi)
 
 
+def groups(cfg: dict) -> list:
+    """The configuration's client groups in upstream order, one per
+    upstream ``[client.N]`` section.  A single ``qos`` block is one
+    group of every client at the configuration's ``cost``."""
+    if "groups" in cfg:
+        return cfg["groups"]
+    return [dict(cfg["qos"], count=int(cfg["clients"]),
+                 cost=int(cfg["cost"]))]
+
+
+def at_limit(cfg: dict) -> str:
+    """Server-wide, as upstream's ``server_soft_limit`` is."""
+    return cfg["qos"]["at_limit"] if "qos" in cfg else cfg["at_limit"]
+
+
 def contracts(cfg: dict, seed: int) -> dict:
-    """Per-client QoS contract, as rates and as int64 inverses."""
+    """Per-client QoS contract and request cost, as rates and as int64
+    inverses.  Clients are laid out group by group, as upstream numbers
+    its client ids; one generator drawn in group order shuffles each
+    Zipf group's weights."""
     n = int(cfg["clients"])
-    q = cfg["qos"]
-    wd = q["weight"]
-    if wd["dist"] == "zipf":
-        w = zipf_weights(n, wd["s"], wd["lo"], wd["hi"])
-        w = w[rng(seed, STREAM_CONTRACT).permutation(n)]
-    elif wd["dist"] == "const":
-        w = np.full(n, float(wd["value"]))
-    else:
-        raise ValueError(f"unknown weight dist {wd['dist']!r}")
-    r = np.full(n, float(q["reservation_ops_per_s"]))
-    lim = np.full(n, float(q["limit_ops_per_s"]))
+    gs = groups(cfg)
+    total = sum(int(g["count"]) for g in gs)
+    if total != n:
+        raise ValueError(f"group counts sum to {total}, not {n}")
+    shuffle = rng(seed, STREAM_CONTRACT)
+    ws, rs, ls, cs = [], [], [], []
+    for g in gs:
+        c, wd = int(g["count"]), g["weight"]
+        if wd["dist"] == "zipf":
+            w = zipf_weights(c, wd["s"], wd["lo"], wd["hi"])
+            ws.append(w[shuffle.permutation(c)])
+        elif wd["dist"] == "const":
+            ws.append(np.full(c, float(wd["value"])))
+        else:
+            raise ValueError(f"unknown weight dist {wd['dist']!r}")
+        if int(g["cost"]) < 1:
+            raise ValueError(f"request cost {g['cost']} is under 1")
+        rs.append(np.full(c, float(g["reservation_ops_per_s"])))
+        ls.append(np.full(c, float(g["limit_ops_per_s"])))
+        cs.append(np.full(c, int(g["cost"]), dtype=np.int64))
+    w, r, lim = (np.concatenate(x) for x in (ws, rs, ls))
     return {"weight": w, "resv": r, "limit": lim,
             "resv_inv": rate_to_inv_ns(r),
             "weight_inv": rate_to_inv_ns(w),
             "limit_inv": rate_to_inv_ns(lim),
-            "allow_limit_break": q["at_limit"] == "allow"}
+            "cost": np.concatenate(cs),
+            "allow_limit_break": at_limit(cfg) == "allow"}
 
 
-def _tag0(inv: np.ndarray, phase: np.ndarray, units: int,
+def _tag0(inv: np.ndarray, phase: np.ndarray, units: np.ndarray,
           sentinel: int) -> np.ndarray:
     return np.where(inv == 0, np.int64(sentinel),
                     (phase * units * inv).astype(np.int64) + 1)
@@ -86,7 +115,7 @@ def preload(cfg: dict, traffic: dict, con: dict, seed: int) -> dict:
     d = int(traffic["preload_depth"])
     if not 0 <= d <= int(cfg["ring"]):
         raise ValueError("preload_depth must be in [0, ring]")
-    units = int(cfg["cost"]) + int(cfg["rho"])
+    units = con["cost"] + int(cfg["rho"])
     phase = rng(seed, STREAM_CONTRACT, 1).random(n)
     return {"depth": d,
             "head_resv": _tag0(con["resv_inv"], phase, units, MAX_TAG),

@@ -9,6 +9,8 @@ records.
 
 from __future__ import annotations
 
+import inspect
+
 import numpy as np
 
 
@@ -22,8 +24,7 @@ def build_state(cfg: dict, con: dict, pre: dict):
     from dmclock_tpu.engine.state import EngineState
 
     n, q = int(cfg["clients"]), int(cfg["ring"])
-    depth, cost, rho = pre["depth"], int(cfg["cost"]), int(cfg["rho"])
-    delta = int(cfg["delta"])
+    depth, rho, delta = pre["depth"], int(cfg["rho"]), int(cfg["delta"])
     sentinel = 1 << 62
 
     def prev_of(head):
@@ -31,7 +32,7 @@ def build_state(cfg: dict, con: dict, pre: dict):
         return jnp.where(jnp.abs(head) == sentinel, 0, head)
 
     @jax.jit
-    def make(rinv, winv, linv, hr, hp, hl):
+    def make(rinv, winv, linv, hr, hp, hl, cost):
         i64 = lambda v: jnp.full((n,), v, jnp.int64)  # noqa: E731
         return EngineState(
             active=jnp.ones((n,), bool), idle=jnp.zeros((n,), bool),
@@ -42,36 +43,44 @@ def build_state(cfg: dict, con: dict, pre: dict):
             prev_limit=prev_of(hl), prev_arrival=i64(0),
             cur_rho=i64(rho), cur_delta=i64(delta),
             head_resv=hr, head_prop=hp, head_limit=hl,
-            head_arrival=i64(0), head_cost=i64(cost), head_rho=i64(rho),
+            head_arrival=i64(0), head_cost=cost, head_rho=i64(rho),
             head_ready=jnp.zeros((n,), bool),
             depth=jnp.full((n,), depth, jnp.int32),
             q_head=jnp.zeros((n,), jnp.int32),
             q_arrival=jnp.zeros((n, q), jnp.int64),
-            q_cost=jnp.full((n, q), cost, jnp.int64))
+            q_cost=jnp.broadcast_to(cost[:, None], (n, q)))
 
     return make(*(jnp.asarray(a, jnp.int64) for a in (
         con["resv_inv"], con["weight_inv"], con["limit_inv"],
-        pre["head_resv"], pre["head_prop"], pre["head_limit"])))
+        pre["head_resv"], pre["head_prop"], pre["head_limit"],
+        con["cost"])))
+
+
+# what the harness hands the chunk entry itself: from the traffic mix,
+# from the contracts, and the loop's own hooks
+HARNESS_KEYS = ("epochs", "dt_epoch_ns", "allow_limit_break", "tracer",
+                "overlap")
 
 
 def chunk_kwargs(cfg: dict, traffic: dict, con: dict) -> dict:
-    """Static knobs of the program's stream chunk for this cell.  Every
-    engine key is read from the configuration, so a configuration that
-    leaves one out fails here instead of running the program's default."""
+    """Keyword arguments of the program's stream chunk for this cell:
+    every key of the configuration's ``engine`` block by name, with the
+    epochs and their length from the traffic mix and the limit-break
+    class from the contracts.  A key the chunk entry does not take
+    fails here, before anything is built, never dropped."""
+    from dmclock_tpu.robust.guarded import run_stream_chunk_guarded
+
+    params = inspect.signature(run_stream_chunk_guarded).parameters
+    takes = {p for p, v in params.items() if v.kind is v.KEYWORD_ONLY}
     eng = cfg["engine"]
-    kw = dict(
-        engine=eng["engine"], epochs=int(traffic["epochs_per_launch"]),
-        m=int(eng["m"]), k=int(eng["k"]),
-        chain_depth=int(eng["chain_depth"]),
-        dt_epoch_ns=int(traffic["epoch_ns"]), waves=int(eng["waves"]),
-        allow_limit_break=bool(con["allow_limit_break"]),
-        with_metrics=bool(eng["with_metrics"]),
-        select_impl=eng["select_impl"], tag_width=int(eng["tag_width"]))
-    if eng["engine"] == "calendar":
-        kw.update(calendar_impl=eng["calendar_impl"],
-                  ladder_levels=int(eng["ladder_levels"]),
-                  wheel_kernel=eng["wheel_kernel"])
-    return kw
+    for key in eng:
+        if key not in takes or key in HARNESS_KEYS:
+            raise TypeError(f"engine setting {key!r}: not a keyword the "
+                            "configuration can give the program's "
+                            "run_stream_chunk_guarded")
+    return dict(eng, epochs=int(traffic["epochs_per_launch"]),
+                dt_epoch_ns=int(traffic["epoch_ns"]),
+                allow_limit_break=bool(con["allow_limit_break"]))
 
 
 def run_chunk(state, epoch0: int, counts, kw: dict, *, tracer=None,

@@ -137,19 +137,20 @@ def _shift_left(x, s):
     return x
 
 
-@partial(jax.jit, static_argnames=("waves", "epoch_ns", "cost", "rho",
-                                   "delta", "allow", "keep_keys"))
-def epoch(ref: Ref, raw, served, t_base, *, waves: int, epoch_ns: int,
-          cost: int, rho: int, delta: int, allow: bool,
+@partial(jax.jit, static_argnames=("waves", "epoch_ns", "rho", "delta",
+                                   "allow", "keep_keys"))
+def epoch(ref: Ref, raw, served, t_base, cost, *, waves: int,
+          epoch_ns: int, rho: int, delta: int, allow: bool,
           keep_keys: bool = False):
     """Ingest one epoch's raw arrivals, judge ``served`` (int[N], the
     program's decisions per client at now = t_base + epoch_ns), and
-    advance by them.  Returns (ref', verdict, keys) where verdict is
-    int64[5] = [admitted, bad_serves, prefix_violation, decisions,
-    eligible_left] (eligible_left: a request that could be served at now
-    is still queued after the epoch) and
-    keys (keep_keys only) = [N, Q] running-max class / key / own class
-    of each served position, for the ordered-stream check."""
+    advance by them.  ``cost`` (int64[N]) is each client's request
+    cost, the same for every request of a client.  Returns (ref',
+    verdict, keys) where verdict is int64[5] = [admitted, bad_serves,
+    prefix_violation, decisions, eligible_left] (eligible_left: a
+    request that could be served at now is still queued after the
+    epoch) and keys (keep_keys only) = [N, Q] running-max class / key /
+    own class of each served position, for the ordered-stream check."""
     n, q = ref.tail.shape
     t_base = jnp.asarray(t_base, jnp.int64)
     now = t_base + epoch_ns
