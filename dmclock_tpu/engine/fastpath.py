@@ -1102,7 +1102,7 @@ def _batch_metrics(met, st: EngineState, *, count, resv, prop, lb,
                    guards_ok, rebase_fallback=False, live=True,
                    ladder_levels_used=0, ladder_base_decisions=0,
                    ladder_fallbacks=0, wheel_occ_hwm=0,
-                   wheel_reslots=0):
+                   wheel_reslots=0, **budget_rows):
     """Fold one batch's contribution into the epoch metrics vector --
     pure reductions over arrays the batch already materialized, so the
     decision stream cannot be perturbed.  A stall is a batch that
@@ -1130,7 +1130,7 @@ def _batch_metrics(met, st: EngineState, *, count, resv, prop, lb,
         cal_ladder_base_decisions=ladder_base_decisions,
         cal_ladder_fallbacks=ladder_fallbacks,
         wheel_occ_hwm=wheel_occ_hwm,
-        wheel_reslots=wheel_reslots))
+        wheel_reslots=wheel_reslots, **budget_rows))
 
 
 def _telemetry_delta(st_post: EngineState, now, cls, key, served_pc,
@@ -1685,16 +1685,24 @@ def _cal_pack(cls, key, kresv, kprop1, kprop2):
 
 def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
                    allow: bool, anticipation_ns: int,
-                   kresv, kprop1, kprop2, b_eff):
+                   kresv, kprop1, kprop2, b_eff, *,
+                   record: bool = False, cap=None):
     """One dense pass of per-client serve iteration, as a lax.scan
     over the step axis (an unrolled step loop at steps=32 exploded
     TPU compile time).
 
     With ``b_eff`` None: measure mode -- serve everything followable
     and return the per-client STOP pack (KEY_INF when the client ran
-    out of work).  With ``b_eff`` a scalar: commit mode -- serves gate
-    on the unit entry pack being strictly below it; returns the final
-    dense state fields and the per-client counters.
+    out of work); with ``record`` also the ``[steps, N]`` pack and key
+    of the unit each step served (KEY_INF where it served nothing), so
+    the requests a commit below B will take are exactly the entries
+    whose pack lies below B.  With ``b_eff`` a scalar: commit mode --
+    serves gate on the unit entry pack being strictly below it, and
+    with ``cap`` (int32[N]) on the client's count so far being below
+    its cap (a client stops at its cap, mid-unit included); returns the
+    final
+    dense state fields and the per-client counters, with ``cap`` also
+    each client's last unit key.
 
     Readiness is classified as ``limit <= now`` at every step: a
     stored ready flag implies it under the monotonic-now restriction
@@ -1721,6 +1729,8 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
         served_resv=jnp.zeros((n,), dtype=jnp.int32),
         lb=jnp.zeros((n,), dtype=jnp.int32),
     )
+    if record or cap is not None:
+        carry0["unit_key"] = jnp.zeros((n,), dtype=jnp.int64)
 
     def step(c, row):
         narr, ncost = row
@@ -1745,6 +1755,11 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
             alive = alive & ~(at_boundary & ~start)
 
         serve = start | (c["in_unit"] & alive)
+        if cap is not None:
+            room = c["served"] < cap
+            start = start & room
+            serve = serve & room
+            alive = alive & room
         phase1 = start & (cls >= CLS_WEIGHT)
 
         nr, np_, nl = _make_tag(
@@ -1800,10 +1815,15 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
             + ((start & (cls == CLS_RESV)) | (serve & c["in_unit"])),
             lb=c["lb"] + (start & (cls >= CLS_LB)),
         )
+        if "unit_key" in c:
+            new["unit_key"] = jnp.where(start, key, c["unit_key"])
+        if record:
+            return new, tuple(jnp.where(serve, v, jnp.int64(KEY_INF))
+                              for v in (new["prev_pk"], new["unit_key"]))
         return new, None
 
     rows = (jnp.stack(arr_rows), jnp.stack(cost_rows))
-    c, _ = lax.scan(step, carry0, rows)
+    c, recs = lax.scan(step, carry0, rows)
 
     if b_eff is None:
         # post-loop stops: a chain still mid-unit cannot be followed
@@ -1824,7 +1844,7 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
             jnp.minimum(stop_pk,
                         jnp.where(nonmono_next, c["prev_pk"], pk)),
             stop_pk)
-        return stop_pk
+        return (stop_pk, recs) if record else stop_pk
 
     fields = dict(head_resv=c["h_resv"], head_prop=c["h_prop"],
                   head_limit=c["h_limit"], head_arrival=c["h_arr"],
@@ -1834,13 +1854,114 @@ def _calendar_pass(state: EngineState, now, arr_rows, cost_rows,
                   depth=c["depth"])
     return (fields, c["qadv"], c["units"], c["served"],
             c["served_resv"], c["lb"], c["prev_pk"], c["unit_cls"],
-            c["cost"])
+            c["cost"], c.get("unit_key"))
+
+
+# ----------------------------------------------------------------------
+# the per-epoch pull budget: an exact cut inside one ladder level
+# ----------------------------------------------------------------------
+#
+# A level commits every request whose unit entry pack lies below its
+# boundary B_eff, and in the serial order those requests run by
+# (class, unit key, client order, j): a unit's in-unit reservation
+# serves follow its entry at once, and a client's units ascend.  So
+# the first r of them are the ones up to the r-th smallest such tuple.
+# The measure pass records each step's unit pack and key ([steps, N]);
+# the crossing class is read off two class counts, the crossing key
+# found by a search over that class's keys that alternates an
+# interpolation probe with a bisection (each probe one count over the
+# record), and the ties at that key go to the clients of lowest order,
+# by a bisection over order.  The result is a per-client cap the
+# commit pass stops at, mid-unit included.
+
+_BUDGET_FRAC_BITS = 20
+
+
+def _budget_caps(req_pk, req_key, b_eff, order, r):
+    """Per-client counts (int32[N]) of the first ``r`` requests, in
+    serial order, of those a level commits below ``b_eff``.
+    ``req_pk`` / ``req_key`` are the measure pass's ``[steps, N]``
+    records, ``r`` a traced int32 below their number.  A pack holds
+    its key relative to the class's origin at the level's entry,
+    clipped, so keys of a class with no candidate at entry share one
+    pack; the order here is the exact (class, key) one, each class's
+    keys rebased on their own minimum."""
+    live = req_pk < b_eff
+    cls = jnp.where(live, req_pk >> 58, CLS_NONE)
+    origin = [jnp.min(jnp.where(cls == c, req_key, jnp.int64(KEY_INF)))
+              for c in (CLS_RESV, CLS_WEIGHT, CLS_LB)]
+    pk = jnp.where(live, (cls << 58) | (req_key - jnp.where(
+        cls == CLS_RESV, origin[0], jnp.where(cls == CLS_WEIGHT,
+                                              origin[1], origin[2]))),
+                   jnp.int64(KEY_INF))
+    n0 = jnp.sum(cls == CLS_RESV, dtype=jnp.int32)
+    n01 = n0 + jnp.sum(cls == CLS_WEIGHT, dtype=jnp.int32)
+    k = jnp.where(r <= n0, CLS_RESV, jnp.where(r <= n01, CLS_WEIGHT,
+                                               CLS_LB))
+    in_k = cls == k
+    lo = jnp.min(jnp.where(in_k, pk, jnp.int64(KEY_INF)))
+    hi = jnp.max(jnp.where(in_k, pk, jnp.int64(-1)))
+    below_k = jnp.where(k == CLS_RESV, 0, jnp.where(k == CLS_WEIGHT, n0,
+                                                    n01))
+
+    # the least P with count(pk <= P) >= r, between lo and hi: both are
+    # packs, c_lo counts the packs below lo and c_hi those up to hi, and
+    # each probe moves one of them onto the pack nearest its side, so
+    # lo is P once c_lo reaches r - 1
+    def probe(c):
+        lo, hi, c_lo, c_hi, it = c
+        span = hi - lo
+        frac = (r - c_lo).astype(jnp.float32) / \
+            (c_hi - c_lo).astype(jnp.float32)
+        q = jnp.clip((frac * (1 << _BUDGET_FRAC_BITS)).astype(jnp.int64),
+                     0, 1 << _BUDGET_FRAC_BITS)
+        low = span & ((1 << _BUDGET_FRAC_BITS) - 1)
+        interp = lo + (span >> _BUDGET_FRAC_BITS) * q + \
+            ((low * q) >> _BUDGET_FRAC_BITS)
+        mid = jnp.where(it % 2 == 0, interp, lo + span // 2)
+        mid = jnp.clip(mid, lo, hi - 1)
+        le = pk <= mid
+        cnt = jnp.sum(le, dtype=jnp.int32)
+        take = cnt >= r
+        lo = jnp.where(take, lo, jnp.min(jnp.where(le, jnp.int64(KEY_INF),
+                                                   pk)))
+        hi = jnp.where(take, jnp.max(jnp.where(le, pk, jnp.int64(-1))), hi)
+        c_lo = jnp.where(take, c_lo, cnt)
+        return (lo, jnp.where(c_lo == r - 1, lo, hi), c_lo,
+                jnp.where(take, cnt, c_hi), it + 1)
+
+    p_star = lax.while_loop(
+        lambda c: c[0] < c[1], probe,
+        (lo, jnp.where(below_k == r - 1, lo, hi), below_k,
+         below_k + jnp.sum(in_k, dtype=jnp.int32), jnp.int32(0)))[0]
+    below = jnp.sum(pk < p_star, axis=0, dtype=jnp.int32)
+    tied = jnp.sum(pk == p_star, axis=0, dtype=jnp.int32)
+    t = r - jnp.sum(below, dtype=jnp.int32)
+
+    # the least client order o with sum(tied where order <= o) >= t
+    has = tied > 0
+
+    def split(c):
+        olo, ohi = c
+        mid = olo + (ohi - olo) // 2
+        take = jnp.sum(jnp.where(has & (order <= mid), tied, 0),
+                       dtype=jnp.int32) >= t
+        return jnp.where(take, olo, mid + 1), jnp.where(take, mid, ohi)
+
+    o_star = lax.while_loop(
+        lambda c: c[0] < c[1], split,
+        (jnp.min(jnp.where(has, order, jnp.int64(KEY_INF))),
+         jnp.max(jnp.where(has, order, jnp.int64(-1)))))[0]
+    first = jnp.sum(jnp.where(order < o_star, tied, 0), dtype=jnp.int32)
+    caps = below + jnp.where(order < o_star, tied,
+                             jnp.where(order == o_star, t - first, 0))
+    return jnp.where(r > 0, caps, 0)
 
 
 def _calendar_batch_core(state: EngineState, now, arr_rows, cost_rows,
                          *, anticipation_ns: int,
                          allow_limit_break: bool,
-                         origins=None, stop_min=None):
+                         origins=None, stop_min=None, budget_left=None):
     """The measure + boundary + commit + promote pipeline of one
     calendar batch, given the prefetched window rows.  Shared by
     :func:`calendar_batch` (one boundary per launch) and the bucketed
@@ -1863,7 +1984,15 @@ def _calendar_batch_core(state: EngineState, now, arr_rows, cost_rows,
     must be BIT-IDENTICAL to the dense reductions they replace (the
     wheel exactness argument, see the kernels wheel section).
 
-    Returns ``(CalendarBatch, b_eff, stop_pk)``."""
+    ``budget_left`` (an int32 scalar, or None for no budget) caps the
+    batch at that many requests: where the requests below B_eff
+    outnumber it, the commit takes only the first ``budget_left`` of
+    them in serial order (:func:`_budget_caps`), so the batch is still
+    a serial prefix, cut inside the level.
+
+    Returns ``(CalendarBatch, b_eff, found)``: ``found`` is the number
+    of requests below B_eff (what the batch commits without a budget),
+    None without a budget."""
     if origins is None:
         cls0, key0 = _classify(state, now, allow_limit_break)
         kresv = jnp.min(jnp.where(cls0 == CLS_RESV, key0, KEY_INF))
@@ -1873,15 +2002,31 @@ def _calendar_batch_core(state: EngineState, now, arr_rows, cost_rows,
     else:
         kresv, kprop1, kprop2, any_cand = origins
 
-    stop_pk = _calendar_pass(state, now, arr_rows, cost_rows,
-                             allow_limit_break, anticipation_ns,
-                             kresv, kprop1, kprop2, None)
-    b_eff = jnp.min(stop_pk) if stop_min is None else stop_min(stop_pk)
-    (fields, qadv, units, served, served_resv, lb, last_pk,
-     last_cls, cost_pc) = _calendar_pass(
-         state, now, arr_rows, cost_rows, allow_limit_break,
-         anticipation_ns, kresv, kprop1, kprop2, b_eff)
-
+    budgeted = budget_left is not None
+    with phase_scope("select"):
+        stop_pk = _calendar_pass(state, now, arr_rows, cost_rows,
+                                 allow_limit_break, anticipation_ns,
+                                 kresv, kprop1, kprop2, None,
+                                 record=budgeted)
+        if budgeted:
+            stop_pk, (req_pk, req_key) = stop_pk
+        b_eff = jnp.min(stop_pk) if stop_min is None \
+            else stop_min(stop_pk)
+    cap = found = None
+    if budgeted:
+        with phase_scope("budget"):
+            found = jnp.sum(req_pk < b_eff, dtype=jnp.int32)
+            cap = lax.cond(
+                found > budget_left,
+                lambda: _budget_caps(req_pk, req_key, b_eff,
+                                     state.order, budget_left),
+                lambda: jnp.full((state.capacity,), len(arr_rows),
+                                 jnp.int32))
+    with phase_scope("commit"):
+        (fields, qadv, units, served, served_resv, lb, last_pk,
+         last_cls, cost_pc, last_key) = _calendar_pass(
+             state, now, arr_rows, cost_rows, allow_limit_break,
+             anticipation_ns, kresv, kprop1, kprop2, b_eff, cap=cap)
     did = served > 0
     popped = did & (qadv > 0)
 
@@ -1914,12 +2059,23 @@ def _calendar_batch_core(state: EngineState, now, arr_rows, cost_rows,
     # order); if its class is >= 1, its entry ran the final promote
     # pass, whose only unseen head is the one that unit's own chain
     # popped into place.
-    lp = jnp.where(did, last_pk, jnp.int64(-1))
-    maxpk = jnp.max(lp)
-    tied = did & (lp == maxpk)
-    excl = jnp.argmax(jnp.where(tied, state.order,
-                                jnp.int64(-1))).astype(jnp.int32)
-    cls_last = jnp.max(jnp.where(tied, last_cls, -1))
+    if budgeted:
+        # by (class, key): packs of a class with no candidate at the
+        # entry share one value (_budget_caps), and a cut level leaves
+        # served clients' requests queued behind its last decision
+        cls_last = jnp.max(jnp.where(did, last_cls, -1))
+        tied = did & (last_cls == cls_last)
+        tied = tied & (last_key == jnp.max(
+            jnp.where(tied, last_key, jnp.int64(MIN_TAG))))
+        excl = jnp.argmax(jnp.where(tied, state.order,
+                                    jnp.int64(-1))).astype(jnp.int32)
+    else:
+        lp = jnp.where(did, last_pk, jnp.int64(-1))
+        maxpk = jnp.max(lp)
+        tied = did & (lp == maxpk)
+        excl = jnp.argmax(jnp.where(tied, state.order,
+                                    jnp.int64(-1))).astype(jnp.int32)
+        cls_last = jnp.max(jnp.where(tied, last_cls, -1))
     do_promote = jnp.any(did) & (cls_last >= CLS_WEIGHT)
     has_req_after = new_state.active & (new_state.depth > 0)
     promoted = new_state.head_ready | \
@@ -1937,14 +2093,18 @@ def _calendar_batch_core(state: EngineState, now, arr_rows, cost_rows,
     # provenance/flight consumer reads it (XLA DCE).
     margin = jnp.where((served > 0) & (b_eff < jnp.int64(KEY_INF)),
                        b_eff - last_pk, jnp.int64(-1))
+    resv_count = jnp.sum(served_resv).astype(jnp.int32)
+    progress_ok = (count > 0) | ~any_cand
+    if budgeted:
+        # a spent budget commits nothing and is no stall
+        progress_ok = progress_ok | (budget_left <= 0)
     batch = CalendarBatch(
-        state=new_state, count=count,
-        resv_count=jnp.sum(served_resv).astype(jnp.int32),
+        state=new_state, count=count, resv_count=resv_count,
         units=units, served=served, served_resv=served_resv, lb=lb,
-        progress_ok=(count > 0) | ~any_cand,
+        progress_ok=progress_ok,
         served_cost=jnp.where(served > 0, cost_pc, jnp.int64(0)),
         margin=margin)
-    return batch, b_eff, stop_pk
+    return batch, b_eff, found
 
 
 def calendar_batch(state: EngineState, now, *, steps: int,
@@ -2210,7 +2370,8 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
                           use_pallas, with_hists: bool = False,
                           with_ledger: bool = False,
                           with_slo: bool = False,
-                          prov0=None, wheel_scan_fn=None):
+                          prov0=None, wheel_scan_fn=None,
+                          budget=None):
     """The fused ladder: a lax.scan over L levels, each a full
     window-prefetch + measure + histogram boundary + commit from the
     previous level's committed state.  Carries only the mutable epoch
@@ -2235,7 +2396,15 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
     place (see the wheel section comment -- every read is bit-equal
     to the dense reduction it replaces, so the committed stream is
     unchanged).  ``wstats`` is then ``(reslots, occ_hwm)`` int64
-    scalars for the metrics plane, else None."""
+    scalars for the metrics plane, else None.
+
+    ``budget`` (None, or the epoch's pull budget state ``(left int32,
+    cut bool)``) caps every level at the budget left
+    (``_calendar_batch_core``'s ``budget_left``).  A level that finds
+    more requests below its boundary than are left is the cut; ``cut``
+    marks that the epoch had one.  The scan then also returns
+    ``(left', cut', left_servable)``, the last what the cut level
+    found beyond the budget left."""
     n = invariant["active"].shape[-1]
     acc0 = dict(units=jnp.zeros((n,), jnp.int32),
                 served=jnp.zeros((n,), jnp.int32),
@@ -2262,24 +2431,32 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
                              allow, scan_fn=wheel_scan_fn)
 
     def level(carry, _):
-        if wheel_scan_fn is not None:
-            mut, acc, tacc, w = carry
-        else:
-            mut, acc, tacc = carry
-            w = None
+        mut, acc, tacc = carry[:3]
+        w = carry[3] if wheel_scan_fn is not None else None
+        bud = carry[-3:] if budget is not None else None
         st = EngineState(**invariant, **mut)
         win = ring_window(st, steps, use_pallas=use_pallas)
         arr_rows, cost_rows = _heads_rows((win.arr, win.cost), steps)
-        batch, b_eff, _ = _calendar_batch_core(
+        with phase_scope("select"):
+            origins = None if w is None else wheel_origins(w)
+        batch, b_eff, found = _calendar_batch_core(
             st, now, arr_rows, cost_rows,
             anticipation_ns=anticipation_ns, allow_limit_break=allow,
-            origins=None if w is None else wheel_origins(w),
+            origins=origins,
             stop_min=None if w is None else functools.partial(
-                _wheel_stop_min, scan_fn=wheel_scan_fn))
+                _wheel_stop_min, scan_fn=wheel_scan_fn),
+            budget_left=None if bud is None else bud[0])
+        if bud is not None:
+            with phase_scope("budget"):
+                left, cut, over = bud
+                crossing = ~cut & (found > left)
+                bud = (left - batch.count, cut | crossing,
+                       over + jnp.where(crossing, found - left, 0))
         if w is not None:
             # fixed-now commit: exactly the served clients moved
-            w = wheel_adjust(w, batch.state, now, allow,
-                             batch.served > 0)
+            with phase_scope("commit"):
+                w = wheel_adjust(w, batch.state, now, allow,
+                                 batch.served > 0)
         new_mut = {f: getattr(batch.state, f) for f in _EPOCH_MUTABLE}
         acc = dict(units=acc["units"] + batch.units,
                    served=acc["served"] + batch.served,
@@ -2319,17 +2496,16 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
         # deterministically repeat it -- same state, same boundary)
         stall = ~batch.progress_ok
         out = (batch.count, batch.resv_count, b_eff, stall)
-        if wheel_scan_fn is not None:
-            return (new_mut, acc, tacc, w), out
-        return (new_mut, acc, tacc), out
+        carry = (new_mut, acc, tacc) + (() if w is None else (w,)) + \
+            (() if bud is None else bud)
+        return carry, out
 
-    if wheel_scan_fn is not None:
-        (mut, acc, tacc, wfin), outs = lax.scan(
-            level, (mut, acc0, tacc0, wheel0), None, length=levels)
-        return mut, acc, tacc, outs, (wfin.reslots, wfin.hwm)
-    (mut, acc, tacc), outs = lax.scan(level, (mut, acc0, tacc0), None,
-                                      length=levels)
-    return mut, acc, tacc, outs, None
+    carry0 = (mut, acc0, tacc0) + (() if wheel0 is None else (wheel0,)) \
+        + (() if budget is None else tuple(budget) + (jnp.int32(0),))
+    fin, outs = lax.scan(level, carry0, None, length=levels)
+    wstats = None if wheel0 is None else (fin[3].reslots, fin[3].hwm)
+    res = fin[:3] + (outs, wstats)
+    return res if budget is None else res + (fin[-3:],)
 
 
 def calendar_batch_bucketed(state: EngineState, now, *, steps: int,
@@ -2462,6 +2638,7 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
                         calendar_impl: str = "minstop",
                         ladder_levels: int = 8,
                         wheel_kernel: str = "xla",
+                        pull_budget: int | None = None,
                         hists=None, ledger=None,
                         flight=None, slo=None,
                         prov=None) -> CalendarEpoch:
@@ -2489,13 +2666,30 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
     telemetry equals the L-batch minstop composition exactly); flight
     records are per CLIENT per BATCH (the calendar engine emits
     per-client counts, not an ordered stream), the cost column
-    carrying the client's committed decisions."""
+    carrying the client's committed decisions.
+
+    ``pull_budget`` (STATIC; None = none) is the server's pull budget
+    per epoch: the epoch commits exactly the first min(pull_budget,
+    servable) requests of the serial order at ``now``, cut inside the
+    level that would overshoot (the pull-budget section comment).  It
+    needs the ladder ("bucketed" or "wheel") and 64-bit tags, and
+    extends the metrics vector by the budget rows
+    (``obs.device.BUDGET_METRIC_NAMES``)."""
     assert tag_width in (32, 64), tag_width
     assert calendar_impl in _CAL_IMPLS, calendar_impl
     wheel = calendar_impl == "wheel"
     bucketed = calendar_impl == "bucketed" or wheel
     levels = int(ladder_levels) if bucketed else 1
     assert levels >= 1, "the ladder needs at least one level"
+    budgeted = pull_budget is not None
+    if budgeted:
+        for ok, need in ((bucketed, 'calendar_impl "bucketed" or '
+                          f'"wheel", not {calendar_impl!r}'),
+                         (tag_width == 64, "64-bit tags"),
+                         (0 <= int(pull_budget) < 1 << 31,
+                          "a budget in [0, 2**31)")):
+            if not ok:
+                raise ValueError(f"pull_budget={pull_budget} needs {need}")
     if wheel:
         wheel_fn = _wheel_resolve(wheel_kernel, state.capacity)
     else:
@@ -2504,7 +2698,7 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
     invariant = {f: getattr(state, f) for f in _EPOCH_INVARIANT}
     mutable0_64 = {f: getattr(state, f) for f in _EPOCH_MUTABLE}
     served0 = jnp.zeros((state.capacity,), dtype=jnp.int32)
-    met0 = obsdev.metrics_zero()
+    met0 = obsdev.metrics_zero(budget=budgeted)
     tele0 = _tele_init(state, hists, ledger, flight, slo, prov)
     need_tele = bool(tele0)
     if narrow32:
@@ -2514,6 +2708,9 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
             met0 = obsdev.metrics_combine(met0, obsdev.metrics_delta(
                 rebase_fallbacks=(~ok0).astype(jnp.int64)))
         carry0 = (mutable0, served0, met0, tele0, ~ok0)
+    elif budgeted:
+        carry0 = (mutable0_64, served0, met0, tele0,
+                  (jnp.int32(pull_budget), jnp.bool_(False)))
     else:
         carry0 = (mutable0_64, served0, met0, tele0)
 
@@ -2521,6 +2718,9 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
         if narrow32:
             mut, acc, met, tele, dead = carry
             st = EngineState(**invariant, **tc.widen(mut))
+        elif budgeted:
+            mut, acc, met, tele, bud = carry
+            st = EngineState(**invariant, **mut)
         else:
             mut, acc, met, tele = carry
             st = EngineState(**invariant, **mut)
@@ -2538,14 +2738,15 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
         if bucketed:
             mut_in = {f: getattr(st, f) for f in _EPOCH_MUTABLE}
             new_mut, lacc, tdelta, \
-                (lvl_count, lvl_resv, _bound, lvl_stall), wstats = \
-                _calendar_ladder_scan(
+                (lvl_count, lvl_resv, _bound, lvl_stall), wstats, \
+                *bud_out = _calendar_ladder_scan(
                     invariant, mut_in, now, steps=steps,
                     levels=levels, anticipation_ns=anticipation_ns,
                     allow=allow_limit_break, use_pallas=use_pallas,
                     with_hists="h" in tele, with_ledger="l" in tele,
                     with_slo="s" in tele, prov0=tele.get("p"),
-                    wheel_scan_fn=wheel_fn)
+                    wheel_scan_fn=wheel_fn,
+                    budget=bud if budgeted else None)
             if wstats is not None:
                 w_reslots, w_hwm = wstats
             hd, ld, sd = (tdelta.get("h"), tdelta.get("l"),
@@ -2614,6 +2815,13 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
         else:
             mut = new_mut
         out = (count, resv_count, progress, lvl_count)
+        budget_rows = {}
+        if budgeted:
+            (left, cut, over), = bud_out
+            budget_rows = dict(
+                budget_cut_epochs=(cut & ~bud[1]).astype(jnp.int64),
+                budget_left_servable=over.astype(jnp.int64))
+            bud = (left, cut)
         if with_metrics:
             with phase_scope("telemetry"):
                 met = _batch_metrics(
@@ -2628,7 +2836,8 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
                     ladder_levels_used=levels_used,
                     ladder_base_decisions=base_decs,
                     ladder_fallbacks=ladder_fb,
-                    wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots)
+                    wheel_occ_hwm=w_hwm, wheel_reslots=w_reslots,
+                    **budget_rows)
         if need_tele:
             with phase_scope("telemetry"):
                 tele = _tele_fold(tele, hd, ld, good, sd)
@@ -2649,6 +2858,7 @@ def scan_calendar_epoch(state: EngineState, now, m: int, *,
                         served.astype(jnp.int64), good,
                         margin=margin_pc, gate=gate_n)
         carry = (mut, acc + served, met, tele, dead) if narrow32 \
+            else (mut, acc + served, met, tele, bud) if budgeted \
             else (mut, acc + served, met, tele)
         return carry, out
 
@@ -2711,16 +2921,22 @@ def epoch_scan_kwargs(engine: str, *, k: int = 0, chain_depth: int = 4,
                       calendar_impl: str = "minstop",
                       ladder_levels: int = 8,
                       wheel_kernel: str = "xla",
+                      pull_budget: int | None = None,
                       anticipation_ns: int = 0,
                       allow_limit_break: bool = False,
                       with_metrics: bool = False) -> dict:
     """Normalize the shared knob set into the kwargs ``engine``'s scan
     accepts: prefix reads k/select_impl/window_m, chain reads
     k/select_impl/chain_depth, and the calendar engine has no [k] cap
-    -- k doubles as its per-client serve-step budget (``steps``)."""
+    -- k doubles as its per-client serve-step budget (``steps``) --
+    and reads ``pull_budget`` where one is given (no other engine
+    takes one)."""
     if engine not in EPOCH_ENGINES:
         raise ValueError(f"unknown epoch engine {engine!r} "
                          f"(one of {EPOCH_ENGINES})")
+    if pull_budget is not None and engine != "calendar":
+        raise ValueError(f"pull_budget is a calendar engine setting; "
+                         f"the {engine!r} engine takes none")
     kw = dict(anticipation_ns=anticipation_ns,
               allow_limit_break=allow_limit_break,
               with_metrics=with_metrics, tag_width=tag_width)
@@ -2733,4 +2949,6 @@ def epoch_scan_kwargs(engine: str, *, k: int = 0, chain_depth: int = 4,
         kw.update(steps=max(k, 1), calendar_impl=calendar_impl,
                   ladder_levels=ladder_levels,
                   wheel_kernel=wheel_kernel)
+        if pull_budget is not None:
+            kw.update(pull_budget=int(pull_budget))
     return kw

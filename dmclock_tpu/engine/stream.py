@@ -175,6 +175,7 @@ def build_stream_chunk(*, engine: str, epochs: int, m: int, k: int = 0,
                        calendar_impl: str = "minstop",
                        ladder_levels: int = 8,
                        wheel_kernel: str = "xla",
+                       pull_budget: Optional[int] = None,
                        ingest: bool = True):
     """Build the pure chunk program ``(state, epoch0, counts, hists,
     ledger, flight) -> StreamChunk`` for one static configuration.
@@ -186,7 +187,9 @@ def build_stream_chunk(*, engine: str, epochs: int, m: int, k: int = 0,
     ``i`` ingests at ``t_base = (epoch0 + i) * dt_epoch_ns`` (wave
     times ``t_base + j * (dt_epoch_ns // waves)``) and serves at
     ``t_base + dt_epoch_ns`` -- the exact round-loop schedule
-    (``robust.supervisor._job_loop``)."""
+    (``robust.supervisor._job_loop``).  ``pull_budget`` (calendar
+    engine only) caps each epoch's pulls
+    (``fastpath.scan_calendar_epoch``)."""
     assert engine in fastpath.EPOCH_ENGINES, engine
     epochs = int(epochs)
     assert epochs >= 1, "a stream chunk needs at least one epoch"
@@ -194,7 +197,7 @@ def build_stream_chunk(*, engine: str, epochs: int, m: int, k: int = 0,
         engine, k=k, chain_depth=chain_depth, select_impl=select_impl,
         tag_width=tag_width, window_m=window_m,
         calendar_impl=calendar_impl, ladder_levels=ladder_levels,
-        wheel_kernel=wheel_kernel,
+        wheel_kernel=wheel_kernel, pull_budget=pull_budget,
         anticipation_ns=anticipation_ns,
         allow_limit_break=allow_limit_break,
         with_metrics=with_metrics)

@@ -83,7 +83,7 @@ def live_executables(cache: str) -> list:
 # ``dmc.<phase>``: trace-time metadata (``op_name``) that changes no
 # op -- the compiled program is the unscoped one's up to metadata and
 # the numbering of instruction names.
-PHASES = ("ingest", "window", "select", "commit", "telemetry")
+PHASES = ("ingest", "window", "select", "commit", "telemetry", "budget")
 _PHASE_PREFIX = "dmc."
 _OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?(%[^\s=]+) = .*?op_name="([^"]*)"')
 # Compiled -> {instruction: phase}, built on first read

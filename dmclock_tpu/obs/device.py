@@ -89,6 +89,16 @@ MET_PALLAS_FALLBACKS = 19  # retired, always 0: a wheel_kernel=
 #                            (PR 21) instead of running the XLA
 #                            reference; the row keeps the layout
 NUM_METRICS = 20
+# rows of a calendar program with a pull budget only
+# (``scan_calendar_epoch(pull_budget=...)``); a program without one
+# keeps the 20-row layout, so its compiled form is untouched
+MET_BUDGET_CUT_EPOCHS = 20   # epochs whose pull budget bound: a level
+#                              found more servable requests below its
+#                              boundary than the budget left
+MET_BUDGET_LEFT_SERVABLE = 21  # requests that cut level found beyond
+#                                the budget left, summed
+NUM_METRICS_BUDGET = 22
+BUDGET_METRIC_NAMES = ("budget_cut_epochs", "budget_left_servable")
 
 METRIC_NAMES = (
     "decisions_total", "decisions_reservation", "decisions_priority",
@@ -112,13 +122,15 @@ RESUME_ROWS = (MET_SUPERVISOR_RESUMES,)
 # under an active trace would leak a tracer into the global --
 # jnp.where folds the numpy constant in at trace time either way.
 _HWM_ROWS = (MET_RING_HWM, MET_WHEEL_OCC_HWM)
-_HWM_MASK = np.zeros((NUM_METRICS,), dtype=bool)
+_HWM_MASK = np.zeros((NUM_METRICS_BUDGET,), dtype=bool)
 for _i in _HWM_ROWS:
     _HWM_MASK[_i] = True
 
 
-def metrics_zero() -> jnp.ndarray:
-    return jnp.zeros((NUM_METRICS,), dtype=jnp.int64)
+def metrics_zero(budget: bool = False) -> jnp.ndarray:
+    """The zero vector; ``budget`` adds the pull-budget rows."""
+    return jnp.zeros((NUM_METRICS_BUDGET if budget else NUM_METRICS,),
+                     dtype=jnp.int64)
 
 
 def metrics_combine(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -126,7 +138,7 @@ def metrics_combine(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     the device-side analog of ``ProfileCombiner``.  Associative and
     commutative, so shards/epochs merge in any order (and through a
     psum-of-counters + pmax-of-hwm on a mesh)."""
-    return jnp.where(_HWM_MASK, jnp.maximum(a, b), a + b)
+    return jnp.where(_HWM_MASK[:a.shape[-1]], jnp.maximum(a, b), a + b)
 
 
 def metrics_delta(*, decisions=0, resv=0, prop=0, limit_break=0,
@@ -137,14 +149,19 @@ def metrics_delta(*, decisions=0, resv=0, prop=0, limit_break=0,
                   cal_ladder_base_decisions=0,
                   cal_ladder_fallbacks=0, ladder_steps=0,
                   supervisor_resumes=0, wheel_occ_hwm=0,
-                  wheel_reslots=0) -> jnp.ndarray:
-    """Build a one-batch delta vector from scalar contributions."""
+                  wheel_reslots=0, budget_cut_epochs=None,
+                  budget_left_servable=None) -> jnp.ndarray:
+    """Build a one-batch delta vector from scalar contributions; the
+    budget rows are there when either budget counter is given."""
     rows = [decisions, resv, prop, limit_break, stalls, ring_hwm,
             guard_trips, ingest_drops, rebase_fallbacks,
             server_dropouts, tracker_resyncs, faults_injected,
             cal_ladder_levels_used, cal_ladder_base_decisions,
             cal_ladder_fallbacks, ladder_steps, supervisor_resumes,
             wheel_occ_hwm, wheel_reslots, 0]
+    if budget_cut_epochs is not None or budget_left_servable is not None:
+        rows += [0 if r is None else r
+                 for r in (budget_cut_epochs, budget_left_servable)]
     return jnp.stack([jnp.asarray(r, dtype=jnp.int64) for r in rows])
 
 
@@ -175,7 +192,7 @@ def metrics_mesh_reduce(vec: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     totals need no host-side gather (the ROADMAP healthy-path item)."""
     from jax import lax
 
-    return jnp.where(_HWM_MASK, pmax_i64(vec, axis_name),
+    return jnp.where(_HWM_MASK[:vec.shape[-1]], pmax_i64(vec, axis_name),
                      lax.psum(vec, axis_name))
 
 
@@ -184,7 +201,7 @@ def metrics_combine_axis(mat: jnp.ndarray) -> jnp.ndarray:
     with the vector's merge semantics (counters add, hwm max) -- the
     local-shard half of a mesh merge (vmapped servers within a shard
     reduce here, then :func:`metrics_mesh_reduce` crosses the mesh)."""
-    return jnp.where(_HWM_MASK, jnp.max(mat, axis=0),
+    return jnp.where(_HWM_MASK[:mat.shape[-1]], jnp.max(mat, axis=0),
                      jnp.sum(mat, axis=0))
 
 
@@ -218,7 +235,8 @@ def metrics_dict(vec) -> dict:
     import numpy as np
 
     v = np.asarray(vec).reshape(-1)
-    return {name: int(v[i]) for i, name in enumerate(METRIC_NAMES)}
+    names = METRIC_NAMES + BUDGET_METRIC_NAMES[:v.size - NUM_METRICS]
+    return {name: int(v[i]) for i, name in enumerate(names)}
 
 
 FAULT_FAMILIES = (

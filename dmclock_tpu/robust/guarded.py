@@ -199,6 +199,7 @@ def run_epoch_guarded(state, now, *, engine: str = "prefix",
                       calendar_impl: str = "minstop",
                       ladder_levels: int = 8,
                       wheel_kernel: str = "xla",
+                      pull_budget: Optional[int] = None,
                       skew_ns: int = 0,
                       hists=None, ledger=None, flight=None, slo=None,
                       prov=None,
@@ -218,6 +219,9 @@ def run_epoch_guarded(state, now, *, engine: str = "prefix",
     sees ``now + skew_ns``.  With ``skew_ns=0`` the first attempt is
     the untouched epoch call -- bit-identical to no wrapper at all
     (chaos differential gate).
+
+    ``pull_budget`` (calendar engine only) caps the epoch at that many
+    pulls, resumes included: a serial resume pops the budget left.
 
     ``hists`` / ``ledger`` / ``flight`` (None = off) are the telemetry
     accumulators of ``fastpath.scan_*_epoch``: pass-through state, so
@@ -245,7 +249,7 @@ def run_epoch_guarded(state, now, *, engine: str = "prefix",
         engine, k=k, chain_depth=chain_depth, select_impl=select_impl,
         tag_width=tag_width, window_m=window_m,
         calendar_impl=calendar_impl, ladder_levels=ladder_levels,
-        wheel_kernel=wheel_kernel,
+        wheel_kernel=wheel_kernel, pull_budget=pull_budget,
         anticipation_ns=anticipation_ns,
         allow_limit_break=allow_limit_break,
         with_metrics=with_metrics)
@@ -329,7 +333,10 @@ def run_epoch_guarded(state, now, *, engine: str = "prefix",
             serial_fb = 1
             _spans.instant(tracer, "guarded.serial_resume", "retry",
                            remaining=remaining)
-            steps = max(remaining, 1) * max(k, 1)
+            # (a budgeted calendar batch stalls only with budget left,
+            # so a budgeted resume pops at least one)
+            steps = max(remaining, 1) * max(k, 1) \
+                if pull_budget is None else pull_budget - total
             run = _jit_serial(steps, allow_limit_break,
                               anticipation_ns)
 
@@ -398,6 +405,7 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
                              calendar_impl: str = "minstop",
                              ladder_levels: int = 8,
                              wheel_kernel: str = "xla",
+                             pull_budget: Optional[int] = None,
                              hists=None, ledger=None, flight=None,
                              slo=None, prov=None,
                              retries: int = 3, base_s: float = 0.05,
@@ -446,7 +454,7 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
         select_impl=select_impl, tag_width=tag_width,
         window_m=window_m, calendar_impl=calendar_impl,
         ladder_levels=ladder_levels, wheel_kernel=wheel_kernel,
-        ingest=do_ingest, donate=False)
+        pull_budget=pull_budget, ingest=do_ingest, donate=False)
     retry_count = [0]
 
     def count_retry(attempt, exc):
@@ -519,7 +527,7 @@ def run_stream_chunk_guarded(state, epoch0: int, counts, *,
             with_metrics=with_metrics, select_impl=select_impl,
             tag_width=tag_width, window_m=window_m,
             calendar_impl=calendar_impl, ladder_levels=ladder_levels,
-            wheel_kernel=wheel_kernel,
+            wheel_kernel=wheel_kernel, pull_budget=pull_budget,
             hists=cur["hists"], ledger=cur["ledger"],
             flight=cur["flight"], slo=cur["slo"], prov=cur["prov"],
             retries=retries, base_s=base_s,

@@ -46,6 +46,56 @@ def build_state(infos, adds, *, capacity=64, ring=64,
     return q.state
 
 
+def budget_population(n=512, ring=8, seed=5, resv=(0.0, 2.0, 5.0)):
+    """Seeded contracts (reservation drawn from ``resv`` ops/s, weight
+    1/2/4, no limit) and up to ``ring`` arrivals per client, every 250 ms from
+    1 s.  Arrivals at shared instants tie many clients' keys, and
+    arrival-dominated retagging interleaves the reservation and
+    weight classes.  Every client arrives in the first wave, so its
+    slot is its id.  Returns ``(infos, adds)`` for :func:`build_state`
+    and the host oracle; at ``now = 3 s`` both classes are served."""
+    import random
+
+    from dmclock_tpu.core import ClientInfo
+
+    rng = random.Random(seed)
+    infos = {c: ClientInfo(rng.choice(resv),
+                           rng.choice([1.0, 2.0, 4.0]), 0)
+             for c in range(n)}
+    adds = [(c, S + j * (S // 4) + rng.choice([0, 0, S // 50]), 1, 1, 1)
+            for j in range(ring) for c in infos
+            if j == 0 or rng.random() < 0.8]
+    return infos, adds
+
+
+@jax.jit
+def serial_pop(state, now, count):
+    """The serial engine's state after ``count`` (traced) pops at the
+    fixed ``now`` -- one compile for every count."""
+    return jax.lax.fori_loop(
+        0, count, lambda _, st: kernels.engine_step(
+            st, now, allow_limit_break=False, anticipation_ns=0)[0],
+        state)
+
+
+@jax.jit
+def servable_count(state, now):
+    """How many requests the serial engine serves at the fixed ``now``
+    before it finds nothing servable."""
+    def more(c):
+        st, n, served = c
+        return served
+
+    def pop(c):
+        st, n, _ = c
+        st, dec = kernels.engine_step(st, now, allow_limit_break=False,
+                                      anticipation_ns=0)
+        served = dec.type == kernels.RETURNING
+        return st, n + served.astype(jnp.int32), served
+    return jax.lax.while_loop(more, pop,
+                              (state, jnp.int32(0), jnp.bool_(True)))[1]
+
+
 def deep_state(infos, depth, t=1 * S, capacity=64):
     adds = [(c, t, 1, 1, 1) for _ in range(depth) for c in infos]
     return build_state(infos, adds, capacity=capacity)

@@ -143,9 +143,36 @@ def test_prefix_chunk_phases_on_v5e(topo, one_chip):
     with jax.default_device(topo.devices[0]):
         compiled = jax.jit(fn).lower(st, epoch0, counts).compile()
     phases = CP.phase_map(compiled)
-    assert set(phases.values()) == set(CP.PHASES)
+    assert set(phases.values()) == set(CP.PHASES) - {"budget"}
     kernels = [line.split(" = ", 1)[0].split()[-1]
                for line in compiled.as_text().splitlines()
                if "tpu_custom_call" in line and " = " in line]
     assert kernels
     assert {phases.get(k) for k in kernels} == {"window"}
+
+
+def test_budgeted_wheel_chunk_compiles_at_100k(topo, one_chip):
+    """The dmc100_100 cell's chunk: 100k clients, ring 32, the wheel
+    calendar on the Pallas bucket scan with one ladder level of 32
+    steps and a pull budget of 1.6M per epoch; both wheel_scan shapes
+    (the class wheels, the stop wheel) are in it, and the cut runs
+    under the budget phase."""
+    from dmclock_tpu.obs import compile_plane as CP
+
+    st = _shapes(jax.eval_shape(lambda: init_state(N, 32)), one_chip)
+    counts = jax.ShapeDtypeStruct((2, N), jnp.int32, sharding=one_chip)
+    epoch0 = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+    fn = SM.build_stream_chunk(
+        engine="calendar", epochs=2, m=1, k=32, dt_epoch_ns=10 ** 8,
+        waves=32, calendar_impl="wheel", ladder_levels=1,
+        wheel_kernel="pallas", allow_limit_break=True,
+        with_metrics=True, pull_budget=1_600_000)
+    with jax.default_device(topo.devices[0]):
+        compiled = jax.jit(fn).lower(st, epoch0, counts).compile()
+    calls = [line.strip() for line in compiled.as_text().splitlines()
+             if line.strip().startswith("%wheel_scan")]
+    for nb in (768, 256):
+        assert any(f" = (s32[{nb},1]" in c for c in calls), nb
+    assert "budget" in set(CP.phase_map(compiled).values())
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 1 << 30
