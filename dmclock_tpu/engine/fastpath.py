@@ -2286,6 +2286,28 @@ def wheel_origins(w: WheelIndex):
     return vals[0], vals[1], vals[2], founds[0] | founds[1] | founds[2]
 
 
+def _wheel_reslot(w: WheelIndex, state: EngineState, now, allow: bool,
+                  moved):
+    """The re-slot :func:`wheel_adjust` builds on: the ``moved``
+    clients' new slots and keys, their old and new buckets (3B where
+    unmoved), the adjusted counts, and the advanced ``(reslots, hwm)``.
+    The ladder's last level, whose index nothing reads, takes only the
+    last of these: no scatter-min, no touched-bucket sets."""
+    nb = 3 * _WHEEL_BUCKETS
+    cls, key = _classify(state, now, allow)
+    new_slot = _wheel_slots(cls, key, w.origin)
+    slot2 = jnp.where(moved, new_slot, w.slot)
+    key2 = jnp.where(moved, key, w.key)
+    out_s = jnp.where(moved, w.slot, jnp.int32(nb))
+    in_s = jnp.where(moved, slot2, jnp.int32(nb))
+    cnt2 = w.cnt.at[out_s].add(jnp.int32(-1), mode="drop") \
+                .at[in_s].add(jnp.int32(1), mode="drop")
+    changed = moved & ((slot2 != w.slot) | (key2 != w.key))
+    stats = (w.reslots + jnp.sum(changed, dtype=jnp.int64),
+             jnp.maximum(w.hwm, jnp.max(cnt2).astype(jnp.int64)))
+    return slot2, key2, out_s, in_s, cnt2, stats
+
+
 def wheel_adjust(w: WheelIndex, state: EngineState, now, allow: bool,
                  moved) -> WheelIndex:
     """In-place re-slot of exactly the ``moved`` clients: decrement
@@ -2298,25 +2320,16 @@ def wheel_adjust(w: WheelIndex, state: EngineState, now, allow: bool,
     idle re-entry, a churn re-slot (section comment; pinned by
     tests/test_calendar_wheel.py's adjust == rebuild gates)."""
     nb = 3 * _WHEEL_BUCKETS
-    cls, key = _classify(state, now, allow)
-    new_slot = _wheel_slots(cls, key, w.origin)
-    slot2 = jnp.where(moved, new_slot, w.slot)
-    key2 = jnp.where(moved, key, w.key)
-    out_s = jnp.where(moved, w.slot, jnp.int32(nb))
-    in_s = jnp.where(moved, slot2, jnp.int32(nb))
-    cnt2 = w.cnt.at[out_s].add(jnp.int32(-1), mode="drop") \
-                .at[in_s].add(jnp.int32(1), mode="drop")
+    slot2, key2, out_s, in_s, cnt2, (reslots, hwm) = _wheel_reslot(
+        w, state, now, allow, moved)
     touched = jnp.zeros((nb,), bool) \
         .at[out_s].set(True, mode="drop") \
         .at[in_s].set(True, mode="drop")
     fresh = jnp.full((nb,), jnp.int64(KEY_INF)) \
         .at[slot2].min(key2, mode="drop")
     bmin2 = jnp.where(touched, fresh, w.bmin)
-    changed = moved & ((slot2 != w.slot) | (key2 != w.key))
-    return WheelIndex(
-        origin=w.origin, cnt=cnt2, bmin=bmin2, slot=slot2, key=key2,
-        reslots=w.reslots + jnp.sum(changed, dtype=jnp.int64),
-        hwm=jnp.maximum(w.hwm, jnp.max(cnt2).astype(jnp.int64)))
+    return WheelIndex(origin=w.origin, cnt=cnt2, bmin=bmin2, slot=slot2,
+                      key=key2, reslots=reslots, hwm=hwm)
 
 
 def _wheel_stop_min(stop_pk, scan_fn):
@@ -2395,8 +2408,10 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
     O(buckets), and only each level's served clients re-slotted in
     place (see the wheel section comment -- every read is bit-equal
     to the dense reduction it replaces, so the committed stream is
-    unchanged).  ``wstats`` is then ``(reslots, occ_hwm)`` int64
-    scalars for the metrics plane, else None.
+    unchanged).  The last level, run after the scan, re-slots only
+    into counts: nothing reads its index.  ``wstats`` is then
+    ``(reslots, occ_hwm)`` int64 scalars for the metrics plane, else
+    None.
 
     ``budget`` (None, or the epoch's pull budget state ``(left int32,
     cut bool)``) caps every level at the budget left
@@ -2430,7 +2445,7 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
         wheel0 = wheel_build(EngineState(**invariant, **mut), now,
                              allow, scan_fn=wheel_scan_fn)
 
-    def level(carry, _):
+    def level(carry, _, final=False):
         mut, acc, tacc = carry[:3]
         w = carry[3] if wheel_scan_fn is not None else None
         bud = carry[-3:] if budget is not None else None
@@ -2453,10 +2468,13 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
                 bud = (left - batch.count, cut | crossing,
                        over + jnp.where(crossing, found - left, 0))
         if w is not None:
-            # fixed-now commit: exactly the served clients moved
+            # fixed-now commit: exactly the served clients moved; after
+            # the last level nothing reads the index, only its stats
             with phase_scope("commit"):
-                w = wheel_adjust(w, batch.state, now, allow,
-                                 batch.served > 0)
+                moved = batch.served > 0
+                w = _wheel_reslot(w, batch.state, now, allow, moved)[-1] \
+                    if final else wheel_adjust(w, batch.state, now, allow,
+                                               moved)
         new_mut = {f: getattr(batch.state, f) for f in _EPOCH_MUTABLE}
         acc = dict(units=acc["units"] + batch.units,
                    served=acc["served"] + batch.served,
@@ -2502,8 +2520,20 @@ def _calendar_ladder_scan(invariant: dict, mut: dict, now, *,
 
     carry0 = (mut, acc0, tacc0) + (() if wheel0 is None else (wheel0,)) \
         + (() if budget is None else tuple(budget) + (jnp.int32(0),))
-    fin, outs = lax.scan(level, carry0, None, length=levels)
-    wstats = None if wheel0 is None else (fin[3].reslots, fin[3].hwm)
+    if wheel0 is None:
+        fin, outs = lax.scan(level, carry0, None, length=levels)
+        wstats = None
+    else:
+        # the wheel peels its last level out of the scan, so that level
+        # carries (reslots, hwm) in place of an index nothing reads
+        fin, outs = carry0, None
+        if levels > 1:
+            fin, outs = lax.scan(level, carry0, None, length=levels - 1)
+        fin, last = level(fin, None, final=True)
+        last = tuple(x[None] for x in last)
+        outs = last if outs is None else tuple(
+            jnp.concatenate(p) for p in zip(outs, last))
+        wstats = fin[3]
     res = fin[:3] + (outs, wstats)
     return res if budget is None else res + (fin[-3:],)
 

@@ -15,6 +15,10 @@ The wheel-specific contracts pinned here:
   (``wheel_origins``) and stop packs (``_wheel_stop_min``), the
   exactness identity the whole engine rests on (the bucket index is
   monotone in the key, so geometry affects discrimination only);
+- **the last level keeps only its stats**: after the ladder's last
+  level nothing reads the adjusted index, so that level computes only
+  ``(reslots, hwm)``; the epoch and both wheel metric rows still equal
+  the full adjust's, and the traced program holds no scatter-min for it;
 - **Pallas parity**: ``wheel_kernel="pallas"`` under
   ``DMCLOCK_WHEEL_INTERPRET=1`` is bit-identical to the XLA kernel,
   and off-TPU without interpret mode (or past the kernel's lane
@@ -32,12 +36,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.extend import core as jcore
+
 from dmclock_tpu.core import ClientInfo
 from dmclock_tpu.core.timebase import NS_PER_SEC
 from dmclock_tpu.engine import fastpath as FP
 from dmclock_tpu.engine import kernels
+from dmclock_tpu.obs import device as obsdev
 
-from engine_helpers import assert_states_equal, deep_state
+from engine_helpers import (assert_states_equal, budget_population,
+                            build_state, deep_state)
 from test_calendar_bucketed import (_JIT, ladder_batch, minstop_batch,
                                     zipf64_state)
 from test_prefix import mixed_qos_state, serial_run_lb
@@ -237,6 +245,102 @@ def test_adjust_equals_rebuild_churn_boundary_reslot():
             jnp.int64(now + 1_000_000)))
     adj2 = FP.wheel_adjust(adj, recycled, now, False, onehot)
     _assert_wheel_equal(adj2, FP.wheel_build(recycled, now, False))
+
+
+# ----------------------------------------------------------------------
+# the ladder's last level: stats only, no index upkeep
+# ----------------------------------------------------------------------
+
+_WHEEL_ROWS = ("wheel_reslots_total", "wheel_bucket_occupancy_hwm")
+
+
+@functools.lru_cache(maxsize=None)
+def _epoch_fn(impl, levels, budget):
+    return jax.jit(functools.partial(
+        FP.scan_calendar_epoch, m=1, steps=4, calendar_impl=impl,
+        ladder_levels=levels, pull_budget=budget, with_metrics=True))
+
+
+@pytest.mark.parametrize("budgeted", [False, True],
+                         ids=["no_budget", "budget"])
+@pytest.mark.parametrize("levels", [1, 2, 3])
+def test_wheel_last_level_stats_are_exact(levels, budgeted):
+    """The wheel epoch, whose last level keeps only ``(reslots, hwm)``,
+    equals the bucketed epoch in stream, state and every other metric
+    row, with or without a budget cut inside the last level; its two
+    wheel rows equal the full ``wheel_adjust`` replayed level by level
+    over each level's served set."""
+    n = 64
+    infos, adds = budget_population(n, ring=8)
+    state = build_state(infos, adds, capacity=n, ring=8)
+    now = jnp.int64(3 * S)
+    budget = None
+    if budgeted:
+        lv = np.asarray(
+            _epoch_fn("bucketed", levels, None)(state, now).level_count)[0]
+        assert lv[-1] > 1
+        budget = int(lv[:-1].sum() + lv[-1] // 2)
+    ew = _epoch_fn("wheel", levels, budget)(state, now)
+    eb = _epoch_fn("bucketed", levels, budget)(state, now)
+    for f in ("count", "resv_count", "progress_ok", "served",
+              "level_count"):
+        assert bool(jnp.array_equal(getattr(ew, f), getattr(eb, f))), f
+    assert_states_equal(ew.state, eb.state)
+    mw = obsdev.metrics_dict(ew.metrics)
+    mb = obsdev.metrics_dict(eb.metrics)
+    assert mw["budget_cut_epochs" if budgeted else "decisions_total"] \
+        == (1 if budgeted else int(np.asarray(ew.count).sum()))
+    for row in _WHEEL_ROWS:
+        mb[row] = mw[row]
+    assert mw == mb
+    # the first l levels of the L-level ladder are the l-level ladder
+    w = FP.wheel_build(state, now, False)
+    served = np.zeros(n, np.int32)
+    for lvl in range(1, levels + 1):
+        e = _epoch_fn("bucketed", lvl, budget)(state, now)
+        w = FP.wheel_adjust(w, e.state, now, False,
+                            jnp.asarray(np.asarray(e.served) > served))
+        served = np.asarray(e.served)
+    assert mw["wheel_reslots_total"] == int(w.reslots) > 0
+    assert mw["wheel_bucket_occupancy_hwm"] == int(w.hwm) > 0
+
+
+def _scatter_mins(jaxpr, runs=1, found=None):
+    """Scatter-mins by bucket count, each counted once per run of the
+    scans around it."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-min":
+            nb, = eqn.outvars[0].aval.shape
+            found[nb] = found.get(nb, 0) + runs
+        inner = runs * eqn.params["length"] \
+            if eqn.primitive.name == "scan" else runs
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    _scatter_mins(sub, inner, found)
+    return found
+
+
+@pytest.mark.parametrize("levels", [1, 2, 3])
+def test_wheel_epoch_scatter_mins_per_level(levels):
+    """The program keeps no bucket-min upkeep after the last level: per
+    batch the class wheels (768 buckets) take one scatter-min for the
+    build and one per non-final level's adjust, the stop wheel (256)
+    one per level -- so at one level only the two ``wheel_scatter``
+    scans remain."""
+    infos, adds = budget_population(32, ring=8)
+    state = build_state(infos, adds, capacity=32, ring=8)
+    m = 2
+    traced = jax.make_jaxpr(functools.partial(
+        FP.scan_calendar_epoch, m=m, steps=4, calendar_impl="wheel",
+        ladder_levels=levels, wheel_kernel="xla", with_metrics=True))(
+            state, jnp.int64(3 * S))
+    nb = FP._WHEEL_BUCKETS
+    assert _scatter_mins(traced.jaxpr) == {
+        3 * nb: m * (1 + (levels - 1)), nb: m * levels}
 
 
 # ----------------------------------------------------------------------
