@@ -45,7 +45,7 @@ import sys
 import threading
 import time
 
-N_CLIENTS = 100_000     # the north-star width (bench.py cfg4)
+N_CLIENTS = 100_000     # the north-star width
 N_SMALL = 4096          # chip-vs-CPU-device digest twins
 
 
@@ -394,22 +394,47 @@ def phase_serve(n: int = N_CLIENTS, n_small: int = N_SMALL,
 
 def phase_rpc(n: int = N_CLIENTS, workers: int = 4,
               requests: int = 64, epochs: int = 16) -> dict:
-    import bench
-    from scripts.loadgen import full_schedule
+    """A loopback ingest server fed by ``workers`` loadgen threads over
+    real sockets, then a replay of the journaled admitted-counts trace
+    that must land on the same chain digest and trace hash."""
+    import tempfile
+
+    from dmclock_tpu.net.journal import ArrivalJournal
+    from dmclock_tpu.net.serve import RpcServeConfig, make_server, run_serve
+    from scripts.loadgen import full_schedule, run_worker
 
     seed = 17
-    row = bench.bench_rpc(workers=workers, requests=requests, n=n,
-                          epochs=epochs, seed=seed)["rpc"]
-    want = sum(nops for sched in full_schedule(
-        seed, workers=workers, requests=requests, n_clients=n,
-        max_nops=3) for _, _, nops in sched)
-    check(row["admitted_ops"] == want,
-          f"rpc: admitted {row['admitted_ops']} of {want} ops")
-    check(row["decisions"] > 0, "rpc: served no decisions")
-    check(row["digest_match"], "rpc: live digest != replay digest")
+    scheds = full_schedule(seed, workers=workers, requests=requests,
+                           n_clients=n, max_nops=3)
+    with tempfile.TemporaryDirectory() as d:
+        cfg = RpcServeConfig(
+            engine="prefix", n=n, epochs=epochs, ckpt_every=2, m=2,
+            k=32, ring=16, waves=6, seed=seed, workdir=d,
+            high_watermark=10 ** 6, wait_ops=1, wait_timeout_s=60)
+        server = make_server(cfg).start()
+        threads = [threading.Thread(
+            target=run_worker, args=("127.0.0.1", server.port, sched),
+            kwargs=dict(timeout_s=0.5, max_attempts=10))
+            for sched in scheds]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        out = run_serve(cfg, server=server)
+        server.stop()
+        trace = ArrivalJournal(d).counts_trace()
+        replay = run_serve(dataclasses.replace(cfg, workdir=None,
+                                               wait_ops=0), trace=trace)
+    want = sum(nops for sched in scheds for _, _, nops in sched)
+    check(out["admitted_ops_traced"] == want,
+          f"rpc: admitted {out['admitted_ops_traced']} of {want} ops")
+    check(out["decisions"] > 0, "rpc: served no decisions")
+    check(replay["digest"] == out["digest"]
+          and replay["trace_sha"] == out["trace_sha"],
+          "rpc: live digest != replay digest")
     return {"requests": workers * requests,
-            "admitted_ops": row["admitted_ops"],
-            "decisions": row["decisions"], "digest": row["digest"]}
+            "admitted_ops": out["admitted_ops_traced"],
+            "decisions": out["decisions"], "digest": out["digest"]}
 
 
 # ----------------------------------------------------------------------

@@ -782,8 +782,8 @@ def _unified_prefix(state: EngineState, now, k: int, *,
         # commit: dense membership is ``packed(key) <= packed boundary``
         # (packed keys are unique).  The boundary pk[count-1] is read as a
         # masked max over the sorted prefix, not a dynamic gather --
-        # scalar gathers from vectors serialize on this stack (PROFILE.md
-        # findings 4/8).
+        # scalar gathers from vectors serialize on this stack (seen on
+        # the retired remote TPU runtime; not chip evidence).
         boundary = jnp.max(jnp.where(served, pks, jnp.int64(-1)))
         sel = pk_dense <= boundary
         new_state = _commit_chains(state, sel, chain)

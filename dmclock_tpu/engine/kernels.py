@@ -106,7 +106,8 @@ def _min_not_0(current, possible):
 #
 # The epoch scans carry ~10 int64 [N] tag/arrival/cost arrays through
 # every iteration; at bench shapes that loop-carried traffic is the
-# bulk of the remaining elementwise cost (PROFILE.md headroom item).
+# bulk of the remaining elementwise cost (seen on the retired remote TPU
+# runtime; not chip evidence).
 # Within one epoch the organic values of each field move only a few ms
 # of virtual time, so they fit an int32 offset from a per-field origin
 # with ~2.1s (+-2^31 ns) to spare.  ``rebase32``/``restore64`` are the
@@ -159,8 +160,9 @@ def restore64(vals32, origin):
 # stop-key ladder both need order statistics of an int64 key vector
 # without sorting it.  Multi-pass dense histograms walk the key space
 # top-down to the exact kk-th smallest element -- O(N) work per round,
-# no sort, no scatter, no scalar gathers (masked reductions only,
-# PROFILE.md finding 10).  Digit width: dense one-hot histograms cost
+# no sort, no scatter, no scalar gathers (masked reductions only: two
+# scalar gathers cost ~0.8 ms per batch on the retired remote TPU
+# runtime; not chip evidence).  Digit width: dense one-hot histograms cost
 # rounds * 2^bits * N compares = (64/b) * 2^b * N, minimized at small
 # b; 4-bit digits (16 rounds of 16-bucket histograms) cost 8x less
 # than 8-bit ones and keep every round a pure vectorized

@@ -3,8 +3,8 @@
 The round-based engine loop pays the host dispatch tax per epoch
 THREE times over: a ``device_get(state.depth)`` for the host-side
 admission clamp, an ``ingest_superwave`` launch, and the epoch-scan
-launch (PROFILE.md findings 17-18, priced continuously by
-``bench.py --spans``).  This
+launch (~17 ms per launch on the retired remote TPU runtime, not chip
+evidence; priced continuously by the span tracer, ``obs.spans``).  This
 module is the RackSched microsecond-dispatch thesis (PAPERS.md)
 applied to that structure: ONE device launch runs a whole **stream
 chunk** of epochs -- a ``lax.scan`` over epochs whose body fuses
@@ -36,8 +36,7 @@ epoch views that reconstruct per-epoch results for the chain digest;
 ``robust.guarded.run_stream_chunk_guarded`` adds retry + the
 guard-trip fallback (a tripped chunk is discarded and re-run on the
 proven round path); ``robust.supervisor`` drives chunks between
-checkpoint boundaries; ``bench.py --engine-loop stream`` chunks its
-own sustained rounds the same way.
+checkpoint boundaries.
 """
 
 from __future__ import annotations

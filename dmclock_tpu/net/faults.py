@@ -83,7 +83,7 @@ def parse_net_fault_spec(spec: Union[str, dict, None]
 
 
 def describe(spec: Optional[dict]) -> str:
-    """Compact spec tag for logs / bench JSON (PR-3 style)."""
+    """Compact spec tag for logs and run records (PR-3 style)."""
     if spec is None:
         return "none"
     parts = [f"seed={int(spec['seed'])}"]

@@ -29,7 +29,7 @@ burning a TPU session on an OOM:
    HBM bandwidth) decides ``compute_bound`` vs ``memory_bound``.
    Peaks come from a small advisory per-chip table
    (:data:`ROOFLINE_PEAKS`); on XLA:CPU everything here is advisory
-   (PROFILE.md) -- the TPU session is the real record.
+   -- the TPU session is the real record.
 
 Everything in this module is host-side arithmetic over abstract
 shapes: it launches nothing, allocates nothing device-side, and cannot
@@ -217,7 +217,7 @@ def capacity_model(**cfg) -> CapacityModel:
 
 def projected_hbm(n: int, **cfg) -> int:
     """Projected resident HBM bytes for ``n`` clients at this knob
-    setting -- the bench JSON line's ``projected_hbm_bytes``."""
+    setting."""
     return capacity_model(**cfg).total(n)
 
 

@@ -3,7 +3,7 @@
 Every hot launch path in the repo routes through a MODULE-LEVEL jit
 cache (the ``engine/queue.py`` ``_JIT_CACHE`` convention), because a
 re-trace costs seconds of host time and a TPU re-compile of the big
-fused programs costs tens of seconds to minutes (PROFILE.md) --
+fused programs costs tens of seconds to minutes --
 a retrace STORM is a silicon-session-killing failure mode that today
 is invisible until the wall clock is already gone.  This module makes
 every one of those caches observable:
@@ -20,7 +20,7 @@ every one of those caches observable:
   ``Compiled.memory_analysis()`` (argument / output / temp /
   generated-code HBM bytes -- what the static ledger in
   ``obs.capacity`` is validated against).  Both are advisory on
-  XLA:CPU (PROFILE.md); the TPU session is the real record.
+  XLA:CPU; the TPU session is the real record.
 - Records export three ways: ``plane().snapshot()`` (JSON-able),
   ``publish_compile_metrics`` (``dmclock_compile_*`` Prometheus
   families), and -- when a tracer is attached via ``set_tracer`` --
@@ -243,9 +243,8 @@ def _sig_diff(old: Dict[str, tuple], new: Dict[str, tuple]
 
 def normalize_cost_analysis(ca) -> Dict[str, float]:
     """ONE normalization of a raw ``cost_analysis()`` value
-    (list-of-dicts on some backends, dict on others) -- shared by the
-    plane's records and ``bench.epoch_cost_analysis`` so the bench row
-    and the compile record can never disagree on the same program."""
+    (list-of-dicts on some backends, dict on others), so every reader
+    of the same program's cost sees the same numbers."""
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}
     out = {}
@@ -446,8 +445,7 @@ class CompilePlane:
             }
 
     def snapshot(self) -> dict:
-        """JSON-able full record (what ``scripts/capacity_report.py``
-        and the bench JSON line derive from)."""
+        """JSON-able full record."""
         return {"totals": self.totals(), "entries": self.entries()}
 
     def retrace_events(self) -> List[Tuple[int, str]]:
@@ -577,7 +575,7 @@ def instrumented_jit(fn, *, cache: str, entry: Any,
 
 
 def aot_record(cache: str, entry: Any, jitted, *args, **kwargs):
-    """Timed+recorded twin of the bench's AOT discipline
+    """Timed+recorded twin of the AOT discipline
     ``jax.jit(fn).lower(*args).compile()``: same Compiled handle back,
     with the lower/compile walls, cost_analysis, and memory_analysis
     folded into the plane under ``(cache, entry)``."""

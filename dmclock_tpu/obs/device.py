@@ -217,7 +217,7 @@ def admission_clamp(counts: jnp.ndarray, headroom: jnp.ndarray):
 
 def metrics_combine_np(acc, *vecs):
     """Host-side mirror of :func:`metrics_combine` over numpy vectors
-    (bench.py merges fetched per-chain vectors with this).  Derives the
+    (merges fetched per-chunk or per-shard vectors).  Derives the
     max rows from the same ``_HWM_ROWS`` as the device mask, so the two
     merges cannot silently diverge."""
     import numpy as np

@@ -276,7 +276,7 @@ def ledger_combine_np(acc, *ledgers):
 
 def ledger_totals(led) -> dict:
     """Column totals of a fetched ledger (host side): counters sum,
-    tardiness max maxes -- the scalar view bench lines carry."""
+    tardiness max maxes."""
     a = np.asarray(led, dtype=np.int64)
     out = {}
     for i, name in enumerate(LEDGER_COL_NAMES):

@@ -127,9 +127,9 @@ def prov_init(n: int, now_ns: int = 0) -> ProvBlock:
     """Fresh block.  ``now_ns`` is the measurement baseline the
     ``last_served`` watermark starts from: staleness of a
     never-served client is measured from BLOCK CREATION, not from
-    virtual t=0 -- a block armed mid-run (the bench's
-    post-calibration reset) must not read every backlogged client as
-    starved since the beginning of time."""
+    virtual t=0 -- a block armed mid-run (after a warm-up) must not
+    read every backlogged client as starved since the beginning of
+    time."""
     import jax.numpy as jnp
 
     return ProvBlock(

@@ -5,8 +5,8 @@ A minimal, dependency-free Prometheus-style registry: counters, gauges
 histograms, and summaries wrapping the repo's ``utils.profile`` timers
 (the reference's ``support/src/profile.h`` accumulators).  Two
 drains: ``prometheus()`` (text exposition format 0.0.4) and
-``snapshot()`` (JSON-able dict, what ``bench.py`` / ``dmc_sim
---metrics-out`` write).
+``snapshot()`` (JSON-able dict, what ``dmc_sim --metrics-out``
+writes).
 
 Durations are exposed in nanoseconds with an explicit ``_ns`` unit in
 the metric name -- the whole repo's tag algebra is int64 ns, and

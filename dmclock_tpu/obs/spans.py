@@ -3,10 +3,11 @@
 The repo can already tell you *what* happened (metrics vector,
 histograms, conformance ledger, flight ring) but not *when* or where
 wall time went: the ~17 ms/launch dispatch tax behind the ROADMAP's
-streaming-serve-loop item exists only as hand-run PROFILE.md
-experiments (findings 17-18).  This module is the instrument that
-prices every round-trip continuously -- a thread-safe, ns-resolution
-structured span tracer for **host-side** events:
+streaming-serve-loop item exists only as hand-run experiments (on
+the retired remote TPU runtime; not chip evidence).  This module is
+the instrument that prices every round-trip continuously -- a
+thread-safe, ns-resolution structured span tracer for **host-side**
+events:
 
 - spans nest (per-thread stacks), carry one of the fixed
   :data:`CATEGORIES`, and record wall ``ts``/``dur`` from
@@ -344,7 +345,7 @@ class SpanTracer:
             return {k: tuple(v) for k, v in self._agg.items()}
 
     def summary(self) -> dict:
-        """JSON-able rollup (what bench.py embeds per workload)."""
+        """JSON-able rollup."""
         with self._mtx:
             return {
                 "spans": self.spans_recorded,

@@ -19,15 +19,16 @@ structured health warnings while a run is still going -- the
   self-time exceeds ``dispatch_share_warn`` of the
   dispatch+device_compute total -- the run is paying more to LAUNCH
   work than to DO it, the exact pathology the ROADMAP's streaming
-  serve loop exists to kill (PROFILE.md findings 17-18);
+  serve loop exists to kill (~17 ms per launch on the retired remote
+  TPU runtime; not chip evidence);
 - **retrace storm** (when a ``compile_plane`` is attached): one jit
   cache entry re-traced >= ``retrace_storm_k`` times inside
   ``retrace_window_s`` -- an argument signature is churning (a shape
   bug, an un-padded dynamic dimension) and every churn pays a full
-  XLA compile, the >15-minute-on-Mosaic failure mode PROFILE.md
-  records.  First compiles are NOT retraces, so the PR-8 AOT
-  pre-compile loop (one fresh entry per chunk length) can never fire
-  this.
+  XLA compile, the >15-minute-on-Mosaic failure mode seen on the
+  retired remote TPU runtime.  First compiles are NOT retraces, so
+  the PR-8 AOT pre-compile loop (one fresh entry per chunk length) can
+  never fire this.
 
 Warnings are structured: one JSON line on ``log`` (default stderr,
 prefixed ``# watchdog:``), a bump of the

@@ -41,8 +41,7 @@ Layering (the ``engine.stream`` convention): this module owns the pure
 device program + host helpers; ``robust.guarded.run_mesh_chunk_guarded``
 adds retry + the guard-trip fallback; ``robust.supervisor`` drives
 chunks between checkpoint boundaries as ``EpochJob(engine_loop="mesh",
-n_shards=S)``; ``bench.py --mode mesh`` runs the aggregate-throughput
-trajectory.  S=1 is bit-identical to the single-engine stream loop BY
+n_shards=S)``.  S=1 is bit-identical to the single-engine stream loop BY
 CONSTRUCTION: both trace ``engine.stream.make_epoch_step``.
 """
 

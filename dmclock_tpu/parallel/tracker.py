@@ -158,8 +158,8 @@ def exchange_schedule(epochs: int, counter_sync_every: int,
     device grid is ``epoch % K == 0``, so epoch 0 always syncs):
     sync count and cadence -- multiply by :func:`counter_view_bytes`
     for the wire totals in the MULTICHIP v2 record.  ``start``
-    matters whenever a measured window begins off-grid (the bench's
-    timed window starts after warmup; ``run_mesh_rounds``'s
+    matters whenever a measured window begins off-grid (a timed
+    window that starts after warmup; ``run_mesh_rounds``'s
     ``round0`` is the same anchor)."""
     every = max(int(counter_sync_every), 1)
     e0 = int(start)

@@ -182,8 +182,8 @@ def plan_events(plan: FaultPlan) -> dict:
 def plan_shard_events(plan: FaultPlan) -> dict:
     """Per-shard form of :func:`plan_events` (``int64[S]`` arrays):
     the exact-match oracle for the ``shard``-labelled
-    ``dmclock_fault_*`` families and the bench's per-shard
-    dropout/resync record rows.  Summing each array reproduces the
+    ``dmclock_fault_*`` families and per-shard dropout/resync record
+    rows.  Summing each array reproduces the
     cluster totals of :func:`plan_events` by construction."""
     prev = np.vstack([np.ones((1, plan.n_servers), dtype=bool),
                       plan.up[:-1]])
@@ -242,10 +242,9 @@ def parse_fault_spec(spec) -> Optional[dict]:
 
 def plan_from_spec(spec: dict, steps: int, n_servers: int) -> FaultPlan:
     """Sample the plan a parsed spec describes for a ``steps`` x
-    ``n_servers`` run -- the one deterministic construction shared by
-    ``EpochJob(fault_plan=...)`` and ``bench.py --fault-plan``, so a
-    bench session and its supervised twin inject the identical
-    schedule."""
+    ``n_servers`` run -- the one deterministic construction behind
+    ``EpochJob(fault_plan=...)``, so a run and its supervised twin
+    inject the identical schedule."""
     kw = dict(spec)
     seed = int(kw.pop("seed", 0))
     return sample_plan(seed, int(steps), int(n_servers), **kw)

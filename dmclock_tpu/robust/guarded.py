@@ -1214,7 +1214,7 @@ class DegradationLadder:
         return 0    # fully degraded already; nothing left to concede
 
     def describe(self) -> list:
-        """JSON-able step list for bench lines / history records."""
+        """JSON-able step list for run records."""
         return [{"knob": s.knob, "from": s.from_value,
                  "to": s.to_value, "reason": s.reason}
                 for s in self.steps]

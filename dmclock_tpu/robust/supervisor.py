@@ -236,7 +236,7 @@ class EpochJob:
     placement: object = "static"
     # degraded-mode mesh serving (docs/ROBUSTNESS.md "Degraded-mode
     # mesh"; engine_loop="mesh" only): a JSON-able fault-plan SPEC
-    # (dict, or the bench's "seed=..,p_dropout=.." string form) --
+    # (dict, or its "seed=..,p_dropout=.." string form) --
     # ``robust.faults.parse_fault_spec`` keys: seed, p_dropout,
     # mean_outage_steps, p_delay, p_dup, max_skew_ns -- sampled
     # deterministically at job start into a ``FaultPlan`` over

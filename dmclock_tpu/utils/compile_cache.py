@@ -1,13 +1,13 @@
 """Where JAX keeps its persistent compilation cache.
 
-Every entry point (``bench.py``, ``chip_smoke.py``, ``dmc_sim``,
-``net.serve``, the supervisor's spawn child) calls
-:func:`enable_compile_cache` once at start-up; nothing calls it at
-import.  ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself
-and wins: this module then sets nothing.  Otherwise the cache lives at
-the fixed path ``<repo>/.jax_cache/`` (git-ignored).  The path is part
-of what a later run must find again, so it is never built from a tmp
-name, a pid or the time.
+Every entry point (``chip_smoke.py``, ``dmc_sim``, ``net.serve``, the
+supervisor's spawn child) calls :func:`enable_compile_cache` once at
+start-up; nothing calls it at import.  ``JAX_COMPILATION_CACHE_DIR``,
+when set, is read by JAX itself and wins: this module then sets
+nothing.  Otherwise the cache lives at the fixed path
+``<repo>/.jax_cache/`` (git-ignored).  The path is part of what a later
+run must find again, so it is never built from a tmp name, a pid or
+the time.
 """
 
 from __future__ import annotations

@@ -5,8 +5,8 @@ Joins the three decision-provenance artifacts a run leaves behind
 (docs/OBSERVABILITY.md "Provenance plane"):
 
 - the **SLO window log** (``--slo``): judged closed-window JSONL rows
-  from ``SloPlane.export_jsonl`` (the supervisor's ``slo_log``, the
-  bench's per-chain rolls, or ``scripts/slo_report.py``'s input);
+  from ``SloPlane.export_jsonl`` (the supervisor's ``slo_log``, or
+  ``scripts/slo_report.py``'s input);
 - the **flight ring dump** (``--flight``, optional): the HBM black
   box's last-R commit records (``obs.flight``), now carrying the
   provenance ``margin``/``gate`` columns;

@@ -85,8 +85,8 @@ def run_worker(host: str, port: int, schedule, *,
                timeout_s: float = 0.5, max_attempts: int = 8,
                fault_spec=None) -> dict:
     """Drive one worker's schedule to completion; returns its client
-    stats (importable -- the in-process bench mode and the tests run
-    workers as threads through this same function)."""
+    stats (importable -- ``chip_smoke.py`` and the tests run workers
+    as threads through this same function)."""
     from dmclock_tpu.net import faults as faults_mod
     from dmclock_tpu.net.client import RpcClient, RpcError
 

@@ -3,8 +3,8 @@
 
 Turns a span file (Chrome trace-event JSON from ``--trace-out``, or
 the tracer's raw JSONL, e.g. a supervisor ``span_log``) into the
-PROFILE.md-style attribution table the hand-run dispatch-tax
-experiments (findings 17-18) produced manually:
+attribution table the hand-run dispatch-tax experiments produced
+manually:
 
     python scripts/trace_report.py trace.json
 
@@ -18,7 +18,7 @@ seconds of launching the run paid per second of device work).
 
 diffs two trace files by (name, category): delta count / total / self
 / mean per row plus the ratio shift -- the before/after tool for the
-streaming-serve-loop refactor (ROADMAP #1): run the same bench with
+streaming-serve-loop refactor: run the same job with
 ``--trace-out`` on both sides and the diff prices exactly what the
 restructuring bought.
 

@@ -432,7 +432,7 @@ class TestRetraceStormWatchdog:
         assert wd.poll_once() == []
 
     def test_real_aot_precompile_loop_never_warns(self):
-        """End-to-end twin of the bench's chunk pre-compile: real
+        """End-to-end twin of a chunk pre-compile loop: real
         jits, one entry per chunk length, watchdog polling after."""
         clock, pl, wd = self._setup(k=2)
         for c in (1, 2, 4):
@@ -542,7 +542,6 @@ class TestDocDrift:
             r"((?:dmclock|sim)_[A-Za-z0-9_{}]*)", re.S)
         names = set()
         files = list((REPO / "dmclock_tpu").rglob("*.py")) \
-            + [REPO / "bench.py"] \
             + list((REPO / "scripts").glob("*.py"))
         for p in files:
             for m in rx.finditer(p.read_text()):
@@ -573,7 +572,6 @@ class TestDocDrift:
         static = self._static_names()
         src = "\n".join(p.read_text() for p in
                         list((REPO / "dmclock_tpu").rglob("*.py"))
-                        + [REPO / "bench.py"]
                         + list((REPO / "scripts").glob("*.py")))
         rotted = []
         for pat in self._doc_patterns():
@@ -613,32 +611,3 @@ class TestDocDrift:
             assert p in names, \
                 f"indexed family {p} is not registered by the " \
                 "capacity-plane publishers"
-
-
-class TestBenchCapacityGate:
-    def test_gate_skips_over_budget_and_passes_under(self,
-                                                     monkeypatch):
-        import bench
-
-        cfg = dict(n=4096, ring=64, engine="prefix", m=4, k=256,
-                   telemetry=True, slo=True)
-        need = obscap.projected_hbm(4096, **{k: v for k, v in
-                                             cfg.items() if k != "n"})
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES",
-                           str(int(need * 0.5)))
-        row = bench._capacity_gate(cfg, engine_loop="stream")
-        assert row is not None and row["capacity_skipped"]
-        assert row["dps"] == 0.0
-        assert row["engine_loop"] == "stream"
-        assert row["projected_hbm_bytes"] > row["hbm_budget_bytes"] \
-            * 0.9
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES",
-                           str(int(need * 10)))
-        assert bench._capacity_gate(cfg) is None
-
-    def test_gate_never_raises_on_garbage(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES", "1000000")
-        assert bench._capacity_gate({"n": 64, "engine": "nonsense",
-                                     "bogus_knob": 1}) is None

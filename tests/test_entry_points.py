@@ -1,16 +1,19 @@
 """Entry-point contracts of the bring-up: no CPU stand-in for the chip,
 and a persistent compile cache placed from outside.
 
-- ``bench.py`` and ``chip_smoke.py`` measure or prove the chip: without
-  a TPU they exit non-zero and print no result line (no rate, no ok).
-- ``utils.compile_cache.enable_compile_cache`` (called by every entry
-  point): ``JAX_COMPILATION_CACHE_DIR`` wins and the code sets nothing;
-  otherwise the cache lives at the fixed ``<repo>/.jax_cache/``.
+- ``perfbench/run.py`` (every cell of ``BENCHMARK.json``) and
+  ``chip_smoke.py`` measure or prove the chip: without a TPU they exit
+  non-zero and print no result line (no rate, no ok).
+- ``utils.compile_cache.enable_compile_cache`` (called by the
+  program's entry points): ``JAX_COMPILATION_CACHE_DIR`` wins and the
+  code sets nothing; otherwise the cache lives at the fixed
+  ``<repo>/.jax_cache/``.
 
 Every case runs in a subprocess: the CPU pin and cache settings of the
 test process itself must not leak into what is being checked.
 """
 
+import json
 import os
 import shutil
 import subprocess
@@ -34,17 +37,15 @@ def _json_lines(stdout: str):
     return [ln for ln in stdout.splitlines() if ln.strip().startswith("{")]
 
 
-@pytest.mark.parametrize("argv", [
-    [],
-    ["--mode", "rpc"],
-    ["--mode", "cfg4", "--calendar-impl", "wheel",
-     "--wheel-kernel", "pallas"],
-], ids=["all", "rpc", "wheel-pallas"])
-def test_bench_refuses_cpu(argv):
-    proc = _run([str(REPO / "bench.py"), *argv])
+@pytest.mark.parametrize("cell", [
+    w["name"] for w in
+    json.loads((REPO / "BENCHMARK.json").read_text())["workloads"]])
+def test_benchmark_refuses_cpu(cell):
+    proc = _run([str(REPO / "perfbench" / "run.py"), "--workload", cell,
+                 "--seed", "1", "--seconds", "1", "--trace", "0"])
     assert proc.returncode != 0
     assert not _json_lines(proc.stdout), proc.stdout
-    assert "no TPU" in proc.stderr
+    assert "needs 1 TPU chip" in proc.stderr
 
 
 def test_chip_smoke_refuses_cpu():

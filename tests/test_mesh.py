@@ -1,6 +1,6 @@
 """Mesh serving plane (parallel.mesh / parallel.cluster mesh rounds /
 robust.guarded.run_mesh_chunk_guarded / robust.supervisor
-``engine_loop="mesh"`` / bench shard planning).
+``engine_loop="mesh"``).
 
 The headline gates:
 
@@ -149,7 +149,7 @@ class TestMeshIdentityGate:
             SV.run_job(mesh_job("prefix-sort",
                                 fault_plan={"bogus_key": 1}))
         # a plain LABEL cannot seed a plan -- rejected, not silently
-        # run benign; the bench's spec-STRING form is accepted
+        # run benign; the spec-STRING form is accepted
         with pytest.raises(ValueError, match="did not parse"):
             SV.run_job(mesh_job("prefix-sort",
                                 fault_plan="chaos-label"))
@@ -209,8 +209,8 @@ class TestMeshScaling:
         assert sched["sync_frac"] == 0.25
         assert TRK.exchange_schedule(5, 1)["syncs"] == 5
         assert TRK.counter_view_bytes(1000) == 16_000
-        # an off-grid window start (the bench's post-warmup timed
-        # window): global epochs [8, 32) at K=7 sync at 14/21/28 only
+        # an off-grid window start (a post-warmup timed window):
+        # global epochs [8, 32) at K=7 sync at 14/21/28 only
         assert TRK.exchange_schedule(24, 7, start=8)["syncs"] == 3
         # a window starting ON the grid counts its first epoch
         assert TRK.exchange_schedule(8, 4, start=8)["syncs"] == 2
@@ -458,53 +458,12 @@ class TestMeshCrashEquivalence:
 
 
 class TestShardPlanning:
-    def test_plan_capacity_inverts_the_client_target(self,
-                                                     monkeypatch):
-        """The shard count FALLS OUT of the client target: with a
-        budget that fits ~B clients/shard, planning N clients yields
-        ceil(N / max_clients) shards."""
-        import bench
-
-        from dmclock_tpu.obs import capacity as obscap
-
-        budget = obscap.projected_hbm(
-            4096, ring=10, engine="prefix", m=2, k=16,
-            telemetry=True, slo=True, stream_chunk=8)
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES",
-                           str(int(budget / 0.9) + 1))
-        plan = bench.plan_mesh_shards(8192, None, ring=10,
-                                      engine="prefix", m=2, k=16,
-                                      stream_chunk=8)
-        assert plan["shards_planned"] >= 2
-        assert plan["max_clients_per_shard"] <= 4096 + 64
-        assert plan["n_shards"] <= len(jax.devices())
-        assert plan["clients_per_shard"] * plan["n_shards"] >= 8192
-        assert plan["projected_hbm_bytes_per_shard"] > 0
-
-    def test_no_budget_falls_back_to_device_count(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES", "0")
-        plan = bench.plan_mesh_shards(1000, None, ring=10,
-                                      engine="prefix", m=2, k=16)
-        assert plan["shards_planned"] is None
-        assert plan["n_shards"] == len(jax.devices())
-
-    def test_oversubscribed_shards_raise(self, monkeypatch):
-        """More shards than attached devices is an error, never a
-        quietly smaller mesh: the bench plan, the rebalance row's
-        mesh, and make_mesh itself."""
-        import bench
-
+    def test_oversubscribed_shards_raise(self):
+        """More shards than attached devices is an error from
+        make_mesh, never a quietly smaller mesh."""
         from dmclock_tpu.parallel import mesh as mesh_mod
 
-        monkeypatch.setenv("DMCLOCK_HBM_BUDGET_BYTES", "0")
         too_many = len(jax.devices()) + 7
-        with pytest.raises(ValueError, match="devices attached"):
-            bench.plan_mesh_shards(1000, too_many, ring=10,
-                                   engine="prefix", m=2, k=16)
-        with pytest.raises(ValueError, match="devices attached"):
-            bench.bench_mesh_rebalance(n_shards=too_many)
         with pytest.raises(ValueError, match="attached"):
             mesh_mod.make_mesh(too_many)
         assert mesh_mod.make_mesh(2).devices.size == 2
@@ -684,7 +643,7 @@ class TestMultichipRecordV2:
         assert mod.load_multichip(str(p))["rebalance"] is None
 
     def test_reader_accepts_v3(self, tmp_path):
-        """v3 carries the rebalance block (bench_mesh_rebalance row):
+        """v3 carries the rebalance block (the shard-rebalancing A/B row):
         placement mode, migrations + per-move log, skew before/after,
         the recovery currencies."""
         import json as _json

@@ -206,8 +206,8 @@ class TestProvAlgebra:
 
     def test_init_baseline(self):
         """A block armed mid-run measures staleness from its own
-        creation time, not from virtual t=0 (the bench's
-        post-calibration reset must not read continuously-served
+        creation time, not from virtual t=0 (a post-warm-up reset
+        must not read continuously-served
         clients as starved since the beginning of the run)."""
         prov = obsprov.prov_init(3, now_ns=500)
         assert np.asarray(prov.last_served).tolist() == [500] * 3

@@ -4,8 +4,8 @@ BASELINE.json demands the TPU backend reproduce CPU ``dmc_sim`` request
 ordering.  Both backends implement the same int64 total order, so the
 complete service trace -- (virtual time, server, client, phase, cost)
 per op -- must match EXACTLY, not statistically.  Run on scaled-down
-versions of the acceptance configs for test-time reasons; ``bench.py``
-and the full configs cover scale.
+versions of the acceptance configs for test-time reasons;
+``tests/test_sim_tpu_fullscale.py`` runs the full configs.
 """
 
 import pytest
