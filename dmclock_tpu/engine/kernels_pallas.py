@@ -21,17 +21,29 @@ of the raw ones -- the (hi signed, lo unsigned) lexicographic order
 IS the int64 order, so per-bucket (min hi, min lo among hi-ties)
 reconstructs the exact int64 minimum.
 
-Shape: the kernel is gridless (gridding it is open perf work) and
-loops the lane rows with ``lax.fori_loop``; iotas are 2-D; all
-temporaries are [sublane, lane] shaped with the bucket axis on
-sublanes, which makes the per-row one-hot compare a plain broadcast
-with no transposes.  tests/test_tpu_compile.py compiles it for v5e
-at n=100k, 3x256 buckets.
+Shape: the kernel is gridless; the lane data sits in VMEM as three
+[rows, 128] int32 planes (the wrapper pads the row count to the loop's
+unroll, padded lanes masked).  The buckets are taken in blocks of up
+to ``_BLOCK`` (64, 8 vregs): for each block one ``lax.fori_loop`` over
+the lane rows broadcasts each row across the block's bucket sublanes
+and keeps LANE-WISE [block, 128] partials -- count += one-hot, high
+word min = min(min, where(one-hot, hi, MAX)) -- in vregs.  Count and
+min are associative and exact in int32, so the lanes are reduced once
+per block, after its rows, not once per row; a second row loop then
+takes the lane-wise min low word among the block's high-word ties and
+reduces it once.  The block writes its rows of the [nb, 1] count and
+min columns, and the first-occupied-bucket scan runs on the finished
+columns.  Iotas are 2-D and the bucket axis is on sublanes, so the
+one-hot compare is a plain broadcast with no transposes.
+tests/test_tpu_compile.py compiles it for v5e at n=100k (3x256 and
+256 buckets) and at the lane budget; tests/test_wheel_scan_kernel.py
+holds it to ``kernels.wheel_scan`` bit for bit in interpret mode.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -43,9 +55,16 @@ from .kernels import KEY_INF, WHEEL_GROUPS
 _LANES = 128
 _I32_MAX = 0x7FFFFFFF
 # padded-lane budget for the gridless call: inputs are 3 int32 planes
-# (12 B/lane) and the one-hot temp is [nb, 128]; 2^19 lanes keeps the
-# whole working set well under the 16MB scoped-VMEM budget
+# (12 B/lane, 6 MB at the budget) and everything else is [nb, 1]
+# columns or a bucket block's [_BLOCK, 128] partials in vregs, so the
+# working set stays well under the 16MB scoped-VMEM budget
 MAX_LANES = 1 << 19
+# buckets per block (a power of two; a block of nb is the largest that
+# divides it): each lane-wise partial is _BLOCK / 8 vregs, and a block
+# carries two of them plus its bucket ids through the row loop
+_BLOCK = 64
+# lane rows per loop step (the wrapper pads the row count to it)
+_UNROLL = 8
 
 
 def wheel_supported(n: int, nb: int) -> bool:
@@ -58,42 +77,67 @@ def wheel_supported(n: int, nb: int) -> bool:
 def _wheel_kernel(bidx_ref, khi_ref, klo_ref, cnt_ref, mhi_ref,
                   mlo_ref, near_ref, *, nb: int, rows: int):
     i32max = jnp.int32(_I32_MAX)
+    block = math.gcd(nb, _BLOCK)     # 8 at least: nb % 8 == 0
+    tile = (block, _LANES)
+    zero = jnp.int32(0)
+    steps = jnp.int32(rows // _UNROLL)
+
+    def lane_rows(ref, g):
+        # the step's _UNROLL lane rows, each broadcast over the
+        # block's bucket sublanes
+        return [jnp.broadcast_to(ref[pl.dslice(g * _UNROLL + j, 1), :],
+                                 tile) for j in range(_UNROLL)]
+
+    def bucket_block(b, carry):
+        base = pl.multiple_of(b * block, block)
+        bid = lax.broadcasted_iota(jnp.int32, tile, 0) + base
+
+        # phase A: occupancy count + min high word, kept lane-wise
+        # ([block, 128] partials) across every row; count and min are
+        # associative and exact, so the lanes are reduced once, after
+        # the rows
+        def phase_a(g, c):
+            cnt, mhi = c
+            for ids, hi in zip(lane_rows(bidx_ref, g),
+                               lane_rows(khi_ref, g)):
+                oh = ids == bid
+                cnt = cnt + oh.astype(jnp.int32)
+                mhi = jnp.minimum(mhi, jnp.where(oh, hi, i32max))
+            return cnt, mhi
+
+        cnt, mhi = lax.fori_loop(
+            zero, steps, phase_a,
+            (jnp.zeros(tile, jnp.int32), jnp.full(tile, i32max, jnp.int32)))
+        cnt = jnp.sum(cnt, axis=1, keepdims=True, dtype=jnp.int32)
+        mhi = jnp.min(mhi, axis=1, keepdims=True)
+        mhi_lanes = jnp.broadcast_to(mhi, tile)
+
+        # phase B: min biased low word among the lanes that tie their
+        # bucket's min high word (lex completion of the int64 min; see
+        # module docstring), lane-wise again
+        def phase_b(g, mlo):
+            for ids, hi, lo in zip(lane_rows(bidx_ref, g),
+                                   lane_rows(khi_ref, g),
+                                   lane_rows(klo_ref, g)):
+                tie = (ids == bid) & (hi == mhi_lanes)
+                mlo = jnp.minimum(mlo, jnp.where(tie, lo, i32max))
+            return mlo
+
+        mlo = lax.fori_loop(zero, steps, phase_b,
+                            jnp.full(tile, i32max, jnp.int32))
+        cnt_ref[pl.dslice(base, block), :] = cnt
+        mhi_ref[pl.dslice(base, block), :] = mhi
+        mlo_ref[pl.dslice(base, block), :] = jnp.min(
+            mlo, axis=1, keepdims=True)
+        return carry
+
+    lax.fori_loop(zero, jnp.int32(nb // block), bucket_block, zero)
+
+    # fused occupancy-min-scan over the finished columns: first
+    # occupied bucket and its stored minimum, before anything leaves
+    # the kernel
     bid = lax.broadcasted_iota(jnp.int32, (nb, 1), 0)
-
-    # phase A: occupancy count + per-bucket min of the high words.
-    # One fori_loop over the [rows, 128] lane grid; each row compares
-    # its 128 lane bucket ids against the [nb, 1] bucket column --
-    # a broadcast one-hot, reduced along lanes.
-    def phase_a(r, c):
-        cnt, mhi = c
-        oh = bidx_ref[pl.dslice(r, 1), :] == bid        # [nb, 128]
-        hi = khi_ref[pl.dslice(r, 1), :]
-        cnt = cnt + jnp.sum(oh, axis=1, keepdims=True,
-                            dtype=jnp.int32)
-        mhi = jnp.minimum(mhi, jnp.min(
-            jnp.where(oh, hi, i32max), axis=1, keepdims=True))
-        return cnt, mhi
-
-    cnt, mhi = lax.fori_loop(
-        0, rows, phase_a,
-        (jnp.zeros((nb, 1), jnp.int32),
-         jnp.full((nb, 1), i32max, jnp.int32)))
-
-    # phase B: per-bucket min of the biased low words among the lanes
-    # that tie the bucket's min high word (lex completion of the
-    # int64 min; see module docstring)
-    def phase_b(r, mlo):
-        oh = bidx_ref[pl.dslice(r, 1), :] == bid
-        tie = oh & (khi_ref[pl.dslice(r, 1), :] == mhi)
-        return jnp.minimum(mlo, jnp.min(
-            jnp.where(tie, klo_ref[pl.dslice(r, 1), :], i32max),
-            axis=1, keepdims=True))
-
-    mlo = lax.fori_loop(0, rows, phase_b,
-                        jnp.full((nb, 1), i32max, jnp.int32))
-
-    # fused occupancy-min-scan: first occupied bucket and its stored
-    # minimum, before anything leaves the kernel
+    cnt, mhi, mlo = cnt_ref[...], mhi_ref[...], mlo_ref[...]
     occ = cnt > 0
     b0 = jnp.min(jnp.where(occ, bid, jnp.int32(nb)))
     at0 = occ & (bid == b0)
@@ -101,9 +145,6 @@ def _wheel_kernel(bidx_ref, khi_ref, klo_ref, cnt_ref, mhi_ref,
     nl = jnp.min(jnp.where(at0, mlo, i32max))
     found = (b0 < nb).astype(jnp.int32)
 
-    cnt_ref[...] = cnt
-    mhi_ref[...] = mhi
-    mlo_ref[...] = mlo
     lane = lax.broadcasted_iota(jnp.int32, (8, 1), 0)
     near_ref[...] = jnp.where(
         lane == 0, b0,
@@ -130,7 +171,7 @@ def wheel_scan_pallas(keys, slot, nb: int, *,
     in-kernel scan needs no grouping)."""
     del groups
     n = keys.shape[0]
-    rows = -(-n // _LANES)
+    rows = -(-n // (_LANES * _UNROLL)) * _UNROLL
     pad = rows * _LANES - n
     if pad:
         keys = jnp.pad(keys, (0, pad))

@@ -89,6 +89,21 @@ def test_wheel_scan_compiles_at_100k(one_chip):
     assert _kernel_calls(compiled) > 0
 
 
+@pytest.mark.parametrize("n,nb", [(N, 256), (KP.MAX_LANES, 3 * 256)],
+                         ids=["stop_wheel_100k", "class_wheels_max_lanes"])
+def test_wheel_scan_compiles(one_chip, n, nb):
+    """The wheel bucket scan's other shapes: the stop wheel (256
+    buckets) at 100k lanes, and the class wheels at the lane budget,
+    so that ``wheel_supported`` holds what fits in scoped VMEM."""
+    assert KP.wheel_supported(n, nb)
+    keys = jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one_chip)
+    slot = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda k, s: KP.wheel_scan_pallas(k, s, nb)).lower(
+            keys, slot).compile()
+    assert _kernel_calls(compiled) > 0
+
+
 def test_wheel_stream_chunk_compiles_at_100k(topo, one_chip):
     """One whole fused stream chunk at 100k clients: the wheel calendar
     on the Pallas bucket scan, ring 128 (so the rotate runs too),
